@@ -1,8 +1,9 @@
 """Roofline analysis (deliverable g): three terms per (arch x shape x
 mesh) from the dry-run artifacts.
 
-Hardware constants (assignment): TPU v5e-class chip, 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants: the ``repro.sim.chip`` row of the device this runs
+on (the TPU v5e row, "TPU v5 lite", when run on the CPU): 197 TFLOP/s
+bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
 
 Term definitions (all PER DEVICE, seconds):
   compute    = HLO_dot_FLOPs_per_device / 197e12
@@ -27,10 +28,8 @@ import os
 from typing import Any, Dict, List, Optional
 
 # chip constants shared with the serving engine's swap-vs-recompute
-# crossover (ONE home: repro/sim/chip.py — re-exported here so the
-# historical `from benchmarks.roofline import PEAK_FLOPS` keeps working
-# and cannot drift from the engine's view)
-from repro.sim.chip import HBM_BW, LINK_BW, PEAK_FLOPS  # noqa: F401
+# crossover (ONE home: repro/sim/chip.py, keyed by device_kind)
+from repro.sim.chip import chip_peaks
 
 _PARAM_CACHE: Dict[str, Dict[str, float]] = {}
 
@@ -121,9 +120,10 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         mem.get("output_size_in_bytes", 0)
     wire = hlo.get("total_wire_bytes", 0.0)
 
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = mem_bytes / HBM_BW
-    t_collective = wire / LINK_BW
+    chip = chip_peaks()
+    t_compute = flops_dev / chip.peak_flops
+    t_memory = mem_bytes / chip.hbm_bw
+    t_collective = wire / chip.link_bw
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_collective}
     dominant = max(terms, key=terms.get)
@@ -150,7 +150,7 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         "model_flops_per_dev": mf_dev,
         "hlo_flops_per_dev": flops_dev,
         "model_over_hlo": useful_frac,
-        "roofline_fraction": ach_flops / PEAK_FLOPS,
+        "roofline_fraction": ach_flops / chip.peak_flops,
         "hbm_gb_per_dev": mem_bytes / 2**30,
         "temp_gb_per_dev": mem.get("temp_size_in_bytes", 0) / 2**30,
         "wire_mb_per_dev": wire / 2**20,
@@ -176,7 +176,7 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         saved = _kv_write_bytes(cell["arch"],
                                 row["prefix_hit_tokens"]) / n_dev
         row["kv_write_bytes_saved_per_dev"] = saved
-        row["t_memory_shared_s"] = max(t_memory - saved / HBM_BW, 0.0)
+        row["t_memory_shared_s"] = max(t_memory - saved / chip.hbm_bw, 0.0)
     if "gather_context_tokens" in cell:
         # paged-attention gather pricing (the kernel_bench paged_attn_*
         # rows, per-cell): the XLA-gather route re-materializes every
@@ -188,7 +188,7 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         extra = 2.0 * _kv_write_bytes(
             cell["arch"], cell["gather_context_tokens"]) / n_dev
         row["gather_bytes_saved_per_dev"] = extra
-        row["t_memory_xla_gather_s"] = t_memory + extra / HBM_BW
+        row["t_memory_xla_gather_s"] = t_memory + extra / chip.hbm_bw
     if cell.get("draft_tokens"):
         # self-speculative serve cell: the verify grid's FLOPs are
         # already in the lowered terms (draft tokens are just extra
@@ -208,7 +208,7 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         row["draft_cost_ratio"] = ratio
         row["draft_flops_per_dev"] = draft_flops_dev
         row["t_compute_spec_s"] = t_compute \
-            + draft_flops_dev / PEAK_FLOPS
+            + draft_flops_dev / chip.peak_flops
         row["spec_acceptance_rate"] = \
             cell.get("accepted_tokens", 0) / cell["draft_tokens"]
     ws = cell.get("weight_stream")
@@ -225,7 +225,7 @@ def roofline_row(cell: Dict[str, Any]) -> Optional[Dict[str, Any]]:
             "weight_stream_unfused_gb_per_dev":
                 ws["weight_bytes_streamed_unfused_per_dev"] / 2**30,
             "fused_traffic_ratio": ws["fused_traffic_ratio"],
-            "t_memory_unfused_s": t_memory + max(extra, 0) / HBM_BW,
+            "t_memory_unfused_s": t_memory + max(extra, 0) / chip.hbm_bw,
         })
     return row
 

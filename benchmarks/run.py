@@ -31,14 +31,21 @@ def _print_rows(name, rows) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import paper_tables
     for fn in paper_tables.ALL:
         name, rows = fn()
         _print_rows(name, rows)
 
+    import jax
+
     from benchmarks.kernel_bench import bench
     rows = bench()
-    _print_rows("kernel_microbench (name,us_per_call,derived)", rows)
+    _print_rows(f"kernel_microbench, wall clock on "
+                f"{jax.devices()[0].platform} (name,us_per_call,derived)",
+                rows)
 
     from benchmarks.roofline import advice, roofline_table
     reports = [p for p in ("dryrun_single.json", "dryrun_multi.json",

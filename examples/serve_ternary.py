@@ -17,8 +17,8 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.models import transformer as tfm
-from repro.serve.engine import Request, ServeEngine, ternarize_model
+from repro.launch.compile_cache import use_compile_cache
+from repro.serve.engine import Request, ServeEngine, init_serving
 
 
 def main():
@@ -34,14 +34,14 @@ def main():
                     help="2-bit packed weights (TPC storage density)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=True)
     if not cfg.supports_decode:
         raise SystemExit(f"{args.arch} is encoder-only; pick a decoder")
     if args.pack:
         cfg = cfg.replace(ternary=cfg.ternary.replace(pack=True))
 
-    params = tfm.init(cfg, jax.random.PRNGKey(0))
-    sparams = ternarize_model(params, cfg)
+    sparams = init_serving(cfg, jax.random.PRNGKey(0))
     engine = ServeEngine(sparams, cfg, batch_slots=args.slots,
                          max_len=args.max_len, chunk=args.chunk)
 
@@ -71,7 +71,8 @@ def main():
           f"budget={engine.token_budget} step_compiles="
           f"{engine.n_step_compiles}")
     print(f"served {len(done)} requests / {total_tokens} tokens "
-          f"in {dt:.1f}s ({total_tokens / dt:.1f} tok/s on CPU); "
+          f"in {dt:.1f}s ({total_tokens / dt:.1f} tok/s wall clock on "
+          f"{jax.devices()[0].platform}, compile included); "
           f"longest prompt {args.max_len} prefilled in "
           f"{-(-args.max_len // args.chunk)} chunks")
     for r in done[:3]:

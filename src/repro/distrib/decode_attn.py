@@ -36,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 
@@ -223,7 +223,7 @@ def sharded_paged_mixed_attention(q, k_pool, v_pool, block_tables,
     if impl == "pallas":
         # pallas_call has no replication rule for shard_map's rep check
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), check_rep=False)(*args)
+                         out_specs=P(), check_vma=False)(*args)
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
                      out_specs=P())(*args)
 

@@ -26,7 +26,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

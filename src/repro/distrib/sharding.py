@@ -31,32 +31,6 @@ from repro.launch.mesh import dp_axis_names, mesh_axis_size
 Rules = Dict[str, Any]
 
 
-def as_shardings(tree, mesh: Mesh):
-    """Map every PartitionSpec leaf to NamedSharding(mesh, spec).
-
-    ``jax.jit`` on 0.4.x accepts only Shardings in in/out_shardings
-    (bare PartitionSpecs require the newer ambient-mesh API); explicit
-    NamedSharding works on every version.
-    """
-    return jax.tree_util.tree_map(
-        lambda x: NamedSharding(mesh, x) if isinstance(x, P) else x,
-        tree, is_leaf=lambda x: isinstance(x, P))
-
-
-def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    Newer jax exposes ``jax.set_mesh``; on 0.4.x the Mesh object itself
-    is the context manager that installs the physical mesh for resource
-    resolution.  Both forms cover what trainer/dryrun need: jitted
-    functions with Named/PartitionSpec shardings resolving against the
-    production mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def make_rules(cfg: ArchConfig, mesh: Mesh,
                batch_shardable: bool = True,
                shard_cache_seq=False,   # False | 'data' | 'model'

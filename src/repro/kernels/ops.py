@@ -2,8 +2,10 @@
 
 Three implementations of the same contract:
 
-  * ``impl='pallas'`` — the Pallas TPU kernels (kernels/tim_matmul.py);
-    interpret=True on CPU so the kernel bodies are validated everywhere.
+  * ``impl='pallas'`` — the Pallas TPU kernels (kernels/tim_matmul.py),
+    compiled on a TPU and run with interpret=True on the CPU backend so
+    the tests validate the kernel bodies; any other backend raises
+    (``pallas_interpret``).
   * ``impl='xla'``    — the same S/T sign-magnitude decomposition written
     as jnp int8 dot_generals.  This is what distributed model code uses
     under jit: XLA fuses the epilogue, GSPMD shards it, and the dry-run
@@ -43,16 +45,15 @@ re-streaming the whole weight matrix from HBM.  With ``fused=True``
     *single* dot_general streams W once; the signed / shifted
     combination is an epilogue over the stacked result.
 
-``fused=False`` keeps the historical multi-launch route — it is the
-parity oracle for the fused path (tests assert bit-identical two-phase
-output) and a fallback if a backend dislikes the fused kernels.
+``fused=False`` keeps the historical multi-launch route as the parity
+oracle for the fused path (tests assert bit-identical two-phase
+output); no production caller takes it.
 ``weight_stream_stats`` quantifies the HBM weight-traffic win; the
 kernel benchmark and tests consume it.
 
 Public contract
 ---------------
-* Production routes: ``impl='auto'`` resolves to 'pallas' on TPU
-  (interpret mode otherwise exercises the same kernel bodies) and
+* Production routes: ``impl='auto'`` resolves to 'pallas' on TPU and
   'xla' elsewhere; 'xla' is also what distributed/jitted model code
   lowers under GSPMD.  Oracles: ``impl='ref'`` (dense dequantized
   matmul) and ``fused=False`` (multi-launch).  The same dispatch
@@ -78,10 +79,21 @@ from repro.kernels import tim_matmul as _tk
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """Whether a Pallas kernel must run in interpret mode: on the CPU
+    backend (where the tests validate the kernel bodies) it must, on a
+    TPU it runs compiled, and any other backend has no route at all."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
         return False
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on backend {backend!r}: use "
+        f"impl='xla' there")
 
 
 def _as_vec(scale, n, dtype=jnp.float32):
@@ -230,17 +242,12 @@ def tim_matmul(x_q: jax.Array, w: TernaryWeight,
 
     def run(xq, i1):
         if impl == "pallas":
-            interp = not _on_tpu()
-            if w.packed:
-                return _tk.tim_matmul_packed_pallas(
-                    _pad_packed_k(xq, w), w.data, w1, w2, jnp.asarray(i1),
-                    need_t=need_t, n_max=n_max, block_m=block_m,
-                    block_n=block_n, block_k=block_k, out_dtype=out_dtype,
-                    interpret=interp)[..., :n]
+            xq = _pad_packed_k(xq, w) if w.packed else xq
             return _tk.tim_matmul_pallas(
-                xq, w.data, w1, w2, jnp.asarray(i1), need_t=need_t,
-                n_max=n_max, block_m=block_m, block_n=block_n,
-                block_k=block_k, out_dtype=out_dtype, interpret=interp)
+                xq, w.data, w1, w2, jnp.asarray(i1), packed=w.packed,
+                need_t=need_t, n_max=n_max, block_m=block_m,
+                block_n=block_n, block_k=block_k, out_dtype=out_dtype,
+                interpret=pallas_interpret())[..., :n]
         wq = w.codes()
         return _st_matmul_xla(xq, wq, w1, w2, jnp.asarray(
             i1, jnp.float32), need_t, n_max).astype(out_dtype)
@@ -254,13 +261,12 @@ def tim_matmul(x_q: jax.Array, w: TernaryWeight,
         i1 = i_scales.pos if i_scales is not None else 1.0
         i2 = i_scales.neg if i_scales is not None else 1.0
         if fused and impl == "pallas":
-            interp = not _on_tpu()
             xf = _pad_packed_k(x2, w) if w.packed else x2
             out = _tk.tim_matmul_fused_pallas(
                 xf, w.data, w1, w2, jnp.asarray(i1), jnp.asarray(i2),
                 packed=w.packed, need_t=need_t, n_max=n_max,
                 block_m=block_m, block_n=block_n, block_k=block_k,
-                out_dtype=out_dtype, interpret=interp)[..., :n]
+                out_dtype=out_dtype, interpret=pallas_interpret())[..., :n]
         elif fused:  # impl == 'xla'
             out = _st_matmul_xla_fused_phases(
                 x2, w.codes(), w1, w2,
@@ -296,14 +302,13 @@ def tim_matmul_bitserial(act_codes: jax.Array, act_step: jax.Array,
         w1, w2 = _dispatch_prelude(w)
         need_t = not w.scales.symmetric
         if impl == "pallas":
-            interp = not _on_tpu()
             if w.packed:
                 a2 = _pad_packed_k(a2, w)
             out = _tk.tim_matmul_bitserial_fused_pallas(
                 a2, w.data, w1, w2, jnp.asarray(act_step),
                 bits=bits, packed=w.packed, need_t=need_t, n_max=n_max,
                 block_m=block_m, block_n=block_n, block_k=block_k,
-                out_dtype=out_dtype, interpret=interp)[..., :n]
+                out_dtype=out_dtype, interpret=pallas_interpret())[..., :n]
         else:
             out = _st_matmul_xla_fused_bitserial(
                 a2, w.codes(), w1, w2,
@@ -349,7 +354,7 @@ def weight_stream_stats(m: int, w: TernaryWeight,
         launches = bits * (2 if asym_w else 1)
     if fused:
         launches = 1
-    m_steps = -(-m // min(block_m, max(8, m)))
+    m_steps = -(-m // _tk.block_rows(m, block_m))
     bytes_per_stream = w.nbytes_hbm * m_steps
     return {
         "launches": launches,

@@ -20,21 +20,22 @@ Grid ``(B, Hk, nc, cb)`` with ``cb = chunk_kv // block_size`` blocks
 per logical chunk and ``nc`` chunks.  Queries are pre-grouped host-side
 to ``(B, Hk, G*Sq, D)`` f32 (pre-scaled by ``D**-0.5``), so one grid
 cell owns all of a KV head's query rows.  Per inner step the index map
-resolves ``tbl[b, c*cb + i]`` and the kernel writes that block's masked
-scores into a ``(G*Sq, chunk_kv)`` VMEM scratch (and its V tile into a
-``(chunk_kv, D)`` scratch); at ``i == cb-1`` the flash update runs over
-the assembled chunk.  Because every reduction (row max, row sum, the
-``p @ V`` contraction) spans exactly the same ``chunk_kv`` positions in
-the same order as the shared scan body in ``nn/attention.
-_online_softmax_scan``, the kernel is **bit-identical** to the XLA
-gather route (asserted exactly in ``tests/test_paged_attention_kernel.
+resolves ``tbl[b, c*cb + i]`` and the kernel copies that block's K and V
+tiles into ``(chunk_kv, D)`` VMEM scratch at sublane offset
+``i*block_size``; at ``i == cb-1`` one dot scores the assembled chunk,
+the chunk's masks apply, and the flash update runs.  (Writing each
+block's scores at lane offset ``i*block_size`` instead is refused by
+the TPU compiler: a dynamic lane offset must be a multiple of 128.)
+Every reduction (row max, row sum, the ``p @ V`` contraction) spans
+exactly the same ``chunk_kv`` positions as the shared scan body in
+``nn/attention._online_softmax_scan``, so the kernel agrees with the
+XLA gather route to a few ulp (``tests/test_paged_attention_kernel.
 py``; the XLA route is in turn bit-identical to the contiguous cache).
 
-VMEM per grid cell: scores ``G*Sq * chunk_kv`` f32 + vbuf ``chunk_kv *
-D`` f32 + the ``(G*Sq, D)`` accumulator — ~0.8 MB at the serving shape
-(G*Sq = 64, chunk_kv = 1024, D = 128).  The block table (and the
-``kv_valid_len`` / ``q_offset`` vectors) live in SMEM via scalar
-prefetch.
+VMEM per grid cell: kbuf + vbuf ``chunk_kv * D`` f32 each + the
+``(G*Sq, D)`` accumulator — ~1.1 MB at the serving shape (G*Sq = 64,
+chunk_kv = 1024, D = 128).  The block table (and the ``kv_valid_len`` /
+``q_offset`` vectors) live in SMEM via scalar prefetch.
 
 Variants
 --------
@@ -58,9 +59,9 @@ Variants
   COMPACTED table (each entry names its logical block explicitly and
   may be invalid) — the per-device table-compaction path.
 
-``interpret=None`` auto-selects interpret mode off-TPU, the same
-discipline as ``kernels/ops.py``: CI validates the kernel body through
-the interpreter, TPUs run it natively.
+``interpret=None`` picks interpret mode on the CPU backend only
+(``kernels/ops.pallas_interpret``): CI validates the kernel body through
+the interpreter, TPUs run it compiled, and any other backend raises.
 """
 from __future__ import annotations
 
@@ -72,21 +73,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
+from repro.kernels.ops import pallas_interpret
 
 NEG_INF = -1e30
+# the scores and p @ V are f32 math, as on the XLA route off-TPU: ask
+# the MXU for full f32 contraction, not a single bf16 pass
+_F32 = jax.lax.Precision.HIGHEST
 
 # Static VMEM contract (timcheck pallas-contract checker;
 # docs/static-analysis.md §vmem-budgets).  Symbols at the serving
 # shape the docstring budgets: gsq = G*Sq = 64 grouped queries,
 # D = 128, block_size = 16, chunk_kv = 1024 (so cb = 64 table entries
-# per chunk).  The assembled-scores + V-chunk scratch dominates
-# (~0.8 MiB); the 1 MiB budget is the ROADMAP's "~1 MB at mixed_32k"
-# figure made machine-checkable.
+# per chunk), up to hk = 8 KV heads of scales per block.  The assembled
+# K + V chunk scratch dominates (1 MiB, ~1.1 MiB in all); the
+# (gsq, chunk_kv) scores are a transient value of the flash step.
 TIMCHECK_VMEM = {
-    "symbols": {"gsq": 64, "d": 128, "bs": 16, "cb": 64},
-    "budgets": {"_paged_attn_kernel": 2 ** 20},
+    "symbols": {"gsq": 64, "d": 128, "bs": 16, "cb": 64, "hk": 8},
+    "budgets": {"_paged_attn_kernel": 3 * 2 ** 19},
 }
+
+
+def _head_scale(scales, h):
+    """(bs, Hk) per-(token, head) scales -> head h's (bs, 1) column, as
+    an exact one-hot lane reduction (no dynamic lane slice)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    return jnp.sum(jnp.where(lane == h, scales.astype(jnp.float32), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _pool_index_maps(tbl_idx):
+    """Index maps over a KV pool viewed as (nb, bs, Hk*D) (``_flat_heads``)
+    and its (nb, bs, Hk) scales.  A K/V block is one head's (bs, D) tile,
+    so its trailing dims are (full bs, D): a (1, D) head slice of an
+    (Hk, D) pair would break the TPU's (8, 128) block tiling.  Scales
+    come as whole (bs, Hk) rows and the kernel picks its head
+    (``_head_scale``)."""
+    def kv_idx(bb, hh, c, i, *refs):
+        return (tbl_idx(bb, c, i, *refs), 0, hh)
+
+    def scale_idx(bb, hh, c, i, *refs):
+        return (tbl_idx(bb, c, i, *refs), 0, 0)
+
+    return kv_idx, scale_idx
+
+
+def _flat_heads(pool):
+    """(nb, bs, Hk, D) -> (nb, bs, Hk*D): a free, contiguous view."""
+    nb, bs, hk, d = pool.shape
+    return pool.reshape(nb, bs, hk * d)
 
 
 def _paged_attn_kernel(*args, nc: int, cb: int, bs: int, sq: int,
@@ -109,7 +143,7 @@ def _paged_attn_kernel(*args, nc: int, cb: int, bs: int, sq: int,
     else:
         o_ref, mo_ref, lo_ref = args[idx:idx + 3]
         idx += 3
-    scores_ref, vbuf_ref, m_ref, l_ref, acc_ref = args[idx:idx + 5]
+    kbuf_ref, vbuf_ref, m_ref, l_ref, acc_ref = args[idx:idx + 5]
 
     b = pl.program_id(0)
     c = pl.program_id(2)
@@ -121,43 +155,52 @@ def _paged_attn_kernel(*args, nc: int, cb: int, bs: int, sq: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k = k_ref[0, :, 0, :]                                # (bs, d)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0]                                         # (bs, d)
+    v = v_ref[0]
     if quant:
         # exactly nn/attention.kv_dequantize: codes*scale in f32, cast
         # to the compute dtype, THEN to f32 for the dot — the bf16
         # round-trip is part of the contract
-        k = (k.astype(jnp.float32)
-             * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
+        h = pl.program_id(1)
+        k = (k.astype(jnp.float32) * _head_scale(ks_ref[0], h)
              ).astype(dequant_dtype)
-        v = (v.astype(jnp.float32)
-             * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
+        v = (v.astype(jnp.float32) * _head_scale(vs_ref[0], h)
              ).astype(dequant_dtype)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-
-    q = q_ref[0, 0]                                      # (gsq, d) f32
-    s = jax.lax.dot_general(q, kf, (((1,), (1,)), ((), ())))  # (gsq, bs)
-
-    e = c * cb + i                                       # table entry
-    lb = lblk_ref[b, e] if compacted else e              # logical block
-    kpos = lb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    if causal:
-        # query row r is (g = r // sq, q = r % sq); position qoff + q
-        rq = jax.lax.broadcasted_iota(jnp.int32, (gsq, 1), 0) % sq
-        qpos = qoff_ref[b] + rq                          # (gsq, 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
-    valid = kpos < vlen_ref[b]                           # (1, bs)
-    if compacted:
-        valid = valid & (sel_ref[b, e] > 0)
-    s = jnp.where(valid, s, NEG_INF)
-
-    scores_ref[:, pl.dslice(i * bs, bs)] = s
-    vbuf_ref[pl.dslice(i * bs, bs), :] = vf
+    # assemble the chunk's K and V along sublanes; the scores of the
+    # whole chunk come from one dot at the last block
+    kbuf_ref[pl.dslice(i * bs, bs), :] = k.astype(jnp.float32)
+    vbuf_ref[pl.dslice(i * bs, bs), :] = v.astype(jnp.float32)
 
     @pl.when(i == cb - 1)
     def _flash():
-        sfull = scores_ref[...]                          # (gsq, ck)
+        ck = cb * bs
+        q = q_ref[0, 0]                                  # (gsq, d) f32
+        sfull = jax.lax.dot_general(q, kbuf_ref[...],
+                                    (((1,), (1,)), ((), ())),
+                                    precision=_F32)      # (gsq, ck)
+        pos = jax.lax.broadcasted_iota(jnp.int32, (1, ck), 1)
+        if compacted:
+            # entry e of this chunk covers logical block lblk[b, e]
+            # and contributes only if sel[b, e]
+            ent = pos // bs
+            kpos = jnp.zeros_like(pos)
+            live = jnp.zeros(pos.shape, jnp.bool_)
+            for e in range(cb):
+                here = ent == e
+                kpos = jnp.where(here, lblk_ref[b, c * cb + e] * bs, kpos)
+                live = live | (here & (sel_ref[b, c * cb + e] > 0))
+            kpos = kpos + pos % bs
+        else:
+            kpos = c * ck + pos                          # (1, ck)
+        if causal:
+            # query row r is (g = r // sq, q = r % sq); position qoff + q
+            rq = jax.lax.broadcasted_iota(jnp.int32, (gsq, 1), 0) % sq
+            qpos = qoff_ref[b] + rq                      # (gsq, 1)
+            sfull = jnp.where(qpos >= kpos, sfull, NEG_INF)
+        valid = kpos < vlen_ref[b]                       # (1, ck)
+        if compacted:
+            valid = valid & live
+        sfull = jnp.where(valid, sfull, NEG_INF)
         m_prev = m_ref[...]                              # (gsq, 1)
         mj = jnp.maximum(m_prev, jnp.max(sfull, axis=-1, keepdims=True))
         m_safe = jnp.maximum(mj, -1e29)
@@ -166,7 +209,7 @@ def _paged_attn_kernel(*args, nc: int, cb: int, bs: int, sq: int,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1,
                                                  keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, vbuf_ref[...], (((1,), (0,)), ((), ())))
+            p, vbuf_ref[...], (((1,), (0,)), ((), ())), precision=_F32)
         m_ref[...] = mj
 
     @pl.when((c == nc - 1) & (i == cb - 1))
@@ -217,7 +260,7 @@ def paged_attention_pallas(
     compacted = logical_blocks is not None
     assert (entry_valid is not None) == compacted
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     assert chunk_kv % bs == 0, (chunk_kv, bs)
     cb = chunk_kv // bs
@@ -247,21 +290,19 @@ def paged_attention_pallas(
         jnp.asarray(0 if q_offset is None else q_offset, jnp.int32),
         (b,))
 
-    def _tbl_idx(bb, hh, c, i, tbl_r, *_):
-        return (tbl_r[bb, c * cb + i], 0, hh, 0)
+    def _tbl_idx(bb, c, i, tbl_r, *_):
+        return tbl_r[bb, c * cb + i]
 
-    def _scale_idx(bb, hh, c, i, tbl_r, *_):
-        return (tbl_r[bb, c * cb + i], 0, hh)
-
+    kv_idx, scale_idx = _pool_index_maps(_tbl_idx)
     in_specs = [
         pl.BlockSpec((1, 1, gsq, d), lambda bb, hh, c, i, *_: (bb, hh, 0, 0)),
-        pl.BlockSpec((1, bs, 1, d), _tbl_idx),
-        pl.BlockSpec((1, bs, 1, d), _tbl_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
     ]
-    inputs = [qg, k_pool, v_pool]
+    inputs = [qg, _flat_heads(k_pool), _flat_heads(v_pool)]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), _scale_idx),
-                     pl.BlockSpec((1, bs, 1), _scale_idx)]
+        in_specs += [pl.BlockSpec((1, bs, hk), scale_idx),
+                     pl.BlockSpec((1, bs, hk), scale_idx)]
         inputs += [k_scale, v_scale]
 
     o_spec = pl.BlockSpec((1, 1, gsq, d), lambda bb, hh, c, i, *_:
@@ -288,7 +329,7 @@ def paged_attention_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((gsq, cb * bs), jnp.float32),   # assembled scores
+            pltpu.VMEM((cb * bs, d), jnp.float32),     # assembled K chunk
             pltpu.VMEM((cb * bs, d), jnp.float32),     # assembled V chunk
             pltpu.VMEM((gsq, 1), jnp.float32),         # running max
             pltpu.VMEM((gsq, 1), jnp.float32),         # running sum
@@ -299,9 +340,10 @@ def paged_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(tbl, lblk, sel, vlen, qoff, *inputs)
 
     if normalize:
@@ -361,7 +403,7 @@ def paged_packed_attention_pallas(
     gsq = g * sq
     quant = k_scale is not None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     assert chunk_kv % bs == 0, (chunk_kv, bs)
     cb = chunk_kv // bs
@@ -380,23 +422,19 @@ def paged_packed_attention_pallas(
     qoff = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (b,))
     seg = jnp.clip(seg_ids, 0, nslots - 1).astype(jnp.int32)
 
-    def _tbl_idx(bb, hh, c, i, tbl_r, lblk_r, sel_r, vlen_r, qoff_r,
-                 seg_r):
-        return (tbl_r[seg_r[bb], c * cb + i], 0, hh, 0)
+    def _tbl_idx(bb, c, i, tbl_r, lblk_r, sel_r, vlen_r, qoff_r, seg_r):
+        return tbl_r[seg_r[bb], c * cb + i]
 
-    def _scale_idx(bb, hh, c, i, tbl_r, lblk_r, sel_r, vlen_r, qoff_r,
-                   seg_r):
-        return (tbl_r[seg_r[bb], c * cb + i], 0, hh)
-
+    kv_idx, scale_idx = _pool_index_maps(_tbl_idx)
     in_specs = [
         pl.BlockSpec((1, 1, gsq, d), lambda bb, hh, c, i, *_: (bb, hh, 0, 0)),
-        pl.BlockSpec((1, bs, 1, d), _tbl_idx),
-        pl.BlockSpec((1, bs, 1, d), _tbl_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
+        pl.BlockSpec((1, bs, d), kv_idx),
     ]
-    inputs = [qg, k_pool, v_pool]
+    inputs = [qg, _flat_heads(k_pool), _flat_heads(v_pool)]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), _scale_idx),
-                     pl.BlockSpec((1, bs, 1), _scale_idx)]
+        in_specs += [pl.BlockSpec((1, bs, hk), scale_idx),
+                     pl.BlockSpec((1, bs, hk), scale_idx)]
         inputs += [k_scale, v_scale]
 
     o_spec = pl.BlockSpec((1, 1, gsq, d), lambda bb, hh, c, i, *_:
@@ -414,7 +452,7 @@ def paged_packed_attention_pallas(
         in_specs=in_specs,
         out_specs=o_spec,
         scratch_shapes=[
-            pltpu.VMEM((gsq, cb * bs), jnp.float32),   # assembled scores
+            pltpu.VMEM((cb * bs, d), jnp.float32),     # assembled K chunk
             pltpu.VMEM((cb * bs, d), jnp.float32),     # assembled V chunk
             pltpu.VMEM((gsq, 1), jnp.float32),         # running max
             pltpu.VMEM((gsq, 1), jnp.float32),         # running sum
@@ -425,9 +463,10 @@ def paged_packed_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="paged_packed_attention",
     )(tbl, lblk, sel, vlen, qoff, seg, *inputs)
 
     o = outs.reshape(b, hk, g, sq, d).transpose(0, 3, 1, 2, 4)

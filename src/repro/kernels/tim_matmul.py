@@ -30,9 +30,9 @@ launch:
   the phase masks are derived in-kernel from the signed codes
   (``pos = max(x, 0)``, ``neg = max(-x, 0)``), the 2–4 int8 MXU passes
   per tile (S/T × phase) accumulate into per-phase VMEM scratch, and
-  the signed ``i1·p1 − i2·p2`` epilogue runs once at ``kk == nsteps-1``.
-  Identical arithmetic to the two-launch path (each phase's f32
-  epilogue is cast to ``out_dtype`` before the subtraction), at half
+  the signed ``i1·p1 − i2·p2`` epilogue runs once at ``kk == nsteps-1``,
+  in f32 with one rounding to ``out_dtype`` (the fused XLA route's
+  arithmetic; the two-launch path rounds each phase first), at half
   the HBM weight traffic.
 
 * ``tim_matmul_bitserial_fused_pallas`` — applies all ``bits``
@@ -42,25 +42,38 @@ launch:
   ``bits``× (and by ``2·bits``× vs the naive route, which also paid an
   all-zero negative phase per plane).
 
-Both fused kernels take dense int8 codes or TPC-style 2-bit packed
-weights (static ``packed`` flag; the unpack runs on the VPU after the
+Every kernel takes dense int8 codes or TPC-style 2-bit packed weights
+(static ``packed`` flag; the unpack runs on the VPU after the
 4x-smaller tile is already in VMEM).
+
+Mosaic constraints
+------------------
+The TPU vector unit has no 8-bit max/abs/shift, so tiles are widened to
+int32 for all elementwise work and narrowed back to int8 only as MXU
+operands.  A packed tile unpacks *field-major*: byte row r of a
+``(bk/4, bn)`` tile holds codes ``4r..4r+3``, and field j of every byte
+goes to rows ``[j*bk/4, (j+1)*bk/4)`` of the unpacked tile — four
+whole-tile planes stacked along sublanes, never a sublane interleave.
+The wrapper permutes each ``bk``-wide K block of X to the same order
+(``_field_major``), so the contraction is unchanged.
 
 Fidelity mode (``n_max``) reproduces the 3-bit flash ADC: counts are
 clamped per L=16-row block (per phase / per plane, exactly as the tile
-hardware saturates each access) before digital accumulation.  This
-forces the K-grid step to L (=16), which is deliberately *not* a
+hardware saturates each access) before digital accumulation.  The K
+step drops to one 128-lane block (the narrowest the TPU tiling admits)
+and the kernel clamps each of its L=16 sub-blocks separately: X is
+masked to one sub-block per MXU pass.  This is deliberately *not* a
 performance path — it exists to validate the paper's accuracy claims,
 while the fast path is what serving uses.  It composes with packed
-weights in every kernel: L=16 is 4-code aligned, so one K step is
-exactly 4 packed bytes and the in-VMEM unpack runs before the clamp.
+weights: the sub-block mask follows the field-major column order.
 
 VMEM tiling: X tile (bm, bk) int8, W tile (bk, bn) int8, up to four
 int32 accumulators (bm, bn) in VMEM scratch.  bm/bn default to 128/256
 — MXU-aligned (multiples of 128 in the lane dim, int8 native) — and
 bk=512 keeps the fused working set at
   128*512 + 512*256 + 4*128*256*4 B ≈ 0.7 MB ≪ 16 MB VMEM,
-leaving headroom for double-buffered HBM→VMEM pipelining.
+leaving headroom for double-buffered HBM→VMEM pipelining.  The int32
+views of a tile are transient values, not scratch.
 """
 from __future__ import annotations
 
@@ -73,12 +86,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import CODES_PER_BYTE
-from repro.kernels._compat import compiler_params
 
 DEFAULT_BM = 128
 DEFAULT_BN = 256
 DEFAULT_BK = 512
 L_BLOCK = 16
+# K step under the ADC clamp: one lane-aligned block of 128 // L_BLOCK
+# sub-blocks, each clamped on its own
+N_MAX_BK = 128
+# int8 operands tile as (32, 128) on the TPU: M tiles round up to it
+M_ALIGN = 32
 
 # Static VMEM contract, machine-checked by repro.analysis (timcheck's
 # pallas-contract checker; docs/static-analysis.md §vmem-budgets).
@@ -100,12 +117,19 @@ TIMCHECK_VMEM = {
 
 def _compiler_params():
     # grid is always (M/bm, N/bn, K/bk) with K innermost-accumulating
-    return compiler_params(("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _dot_i32(a, b):
+    """int8 MXU pass over int32-held codes (|codes| <= 127).  The
+    precision is pinned: an ambient ``default_matmul_precision`` of
+    'highest' would otherwise ask Mosaic for an f32 contraction, which
+    it refuses for int8 operands."""
     return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a.astype(jnp.int8), b.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.int32)
 
 
 def _epilogue(s, t, w1, w2, i1, out_dtype):
@@ -121,10 +145,59 @@ def _epilogue(s, t, w1, w2, i1, out_dtype):
 
 def _clamped_st(s, t, n_max):
     """ADC saturation for one access: clamp (n, k) at n_max, return the
-    clamped counts re-expressed in the (S, T) basis."""
-    n = jnp.minimum((t + s) // 2, n_max)
-    k = jnp.minimum((t - s) // 2, n_max)
+    clamped counts re-expressed in the (S, T) basis.  T ± S is twice a
+    non-negative count, so the halving is an exact shift."""
+    n = jnp.minimum((t + s) >> 1, n_max)
+    k = jnp.minimum((t - s) >> 1, n_max)
     return n - k, n + k
+
+
+def _unpack2b_tile(pw):
+    """(bkp, bn) uint8 -> (4*bkp, bn) int32 ternary codes, field-major:
+    row ``j*bkp + r`` is field j of byte row r (K index ``4r + j``).
+
+    Field encoding per core/packing.py: 00→0, 01→+1, 11→-1.
+    """
+    p = pw.astype(jnp.int32)
+    planes = []
+    for j in range(CODES_PER_BYTE):
+        f = (p >> (2 * j)) & 0b11
+        planes.append(jnp.where(f == 1, 1, jnp.where(f == 3, -1, 0)))
+    return jnp.concatenate(planes, axis=0)
+
+
+def _w_tile(w_ref, packed: bool):
+    w = w_ref[...]
+    return _unpack2b_tile(w) if packed else w.astype(jnp.int32)
+
+
+def _l_blocks(x, n_max, packed: bool):
+    """The X tile as the MXU sees it: whole, or — under the ADC clamp —
+    one copy per L=16 K sub-block with every other column zeroed.
+    Columns are in the W tile's row order (field-major when packed)."""
+    if n_max is None:
+        return [x]
+    bk = x.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    if packed:
+        bkp = bk // CODES_PER_BYTE
+        col = (col % bkp) * CODES_PER_BYTE + col // bkp
+    blk = col // L_BLOCK
+    return [jnp.where(blk == lb, x, 0) for lb in range(-(-bk // L_BLOCK))]
+
+
+def _st_passes(x, w, aw, n_max, packed: bool):
+    """(S, T) partials of one tile, ADC-clamped per L block if n_max."""
+    s_sum = t_sum = None
+    for xl in _l_blocks(x, n_max, packed):
+        s = _dot_i32(xl, w)
+        t = None if aw is None else _dot_i32(jnp.abs(xl), aw)
+        if n_max is not None:
+            s, t = _clamped_st(s, t, n_max)
+        s_sum = s if s_sum is None else s_sum + s
+        if t is not None:
+            t_sum = t if t_sum is None else t_sum + t
+    return s_sum, t_sum
 
 
 def _tim_kernel(x_ref, w_ref, w1_ref, w2_ref, i1_ref, o_ref,
@@ -139,21 +212,12 @@ def _tim_kernel(x_ref, w_ref, w1_ref, w2_ref, i1_ref, o_ref,
         if need_t:
             t_acc[...] = jnp.zeros_like(t_acc)
 
-    x = x_ref[...]
-    w = _unpack2b_tile(w_ref[...]) if packed else w_ref[...]
-    s = _dot_i32(x, w)
-    t = _dot_i32(jnp.abs(x), jnp.abs(w)) if need_t else None
-
-    if n_max is None:
-        s_acc[...] += s
-        if need_t:
-            t_acc[...] += t
-    else:
-        # ADC fidelity: this K-step is one L=16 block; clamp n and k at
-        # n_max before accumulating (bitline voltage saturation).
-        sc, tc = _clamped_st(s, t, n_max)
-        s_acc[...] += sc
-        t_acc[...] += tc
+    x = x_ref[...].astype(jnp.int32)
+    w = _w_tile(w_ref, packed)
+    s, t = _st_passes(x, w, jnp.abs(w) if need_t else None, n_max, packed)
+    s_acc[...] += s
+    if need_t:
+        t_acc[...] += t
 
     @pl.when(kk == nsteps - 1)
     def _done():
@@ -173,6 +237,14 @@ def _pad_dim(a, axis, mult):
     return jnp.pad(a, widths)
 
 
+def _field_major(x, bk: int):
+    """Permute each bk-wide K block of X into the field-major order of
+    an unpacked packed-weight tile (column ``j*bk/4 + r`` = K ``4r+j``)."""
+    m, kp = x.shape
+    x = x.reshape(m, kp // bk, bk // CODES_PER_BYTE, CODES_PER_BYTE)
+    return x.transpose(0, 1, 3, 2).reshape(m, kp)
+
+
 class _TilePlan(NamedTuple):
     """Shared tiling for every TiM kernel wrapper: clamped block sizes,
     block-padded operands, the (M, N, K) grid, and the block specs."""
@@ -189,21 +261,23 @@ class _TilePlan(NamedTuple):
     out_shape: tuple
 
 
-def _tile_plan(x, w_data, w1, w2, *, packed: bool, block_m: int,
-               block_n: int, block_k: int) -> _TilePlan:
+def _tile_plan(x, w_data, w1, w2, *, packed: bool, n_max: Optional[int],
+               block_m: int, block_n: int, block_k: int) -> _TilePlan:
     m, kdim = x.shape
     n = w_data.shape[1]
-    bm = min(block_m, max(8, m))
-    bk = min(block_k, kdim)
+    bm = block_rows(m, block_m)
+    bk = min(N_MAX_BK if n_max is not None else block_k, kdim)
     if packed:
         bk -= bk % CODES_PER_BYTE
     bn = min(block_n, n)
 
     x = _pad_dim(_pad_dim(x, 0, bm), 1, bk)
+    if packed:
+        x = _field_major(x, bk)
     wk = bk // CODES_PER_BYTE if packed else bk
     w_data = _pad_dim(_pad_dim(w_data, 0, wk), 1, bn)
-    w1 = _pad_dim(w1, 0, bn)
-    w2 = _pad_dim(w2, 0, bn)
+    w1 = _pad_dim(w1.reshape(1, n), 1, bn)
+    w2 = _pad_dim(w2.reshape(1, n), 1, bn)
     mp, kp = x.shape
     np_ = w_data.shape[1]
     return _TilePlan(
@@ -212,13 +286,18 @@ def _tile_plan(x, w_data, w1, w2, *, packed: bool, block_m: int,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((wk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_spec=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=(mp, np_),
     )
+
+
+def block_rows(m: int, block_m: int) -> int:
+    """M tile: ``block_m`` rows, or all M rounded up to the int8 tile."""
+    return min(block_m, -(-m // M_ALIGN) * M_ALIGN)
 
 
 def _acc_shapes(plan: _TilePlan, flags) -> list:
@@ -228,33 +307,39 @@ def _acc_shapes(plan: _TilePlan, flags) -> list:
             for on in flags]
 
 
+def _check_k(kdim, w_data, packed: bool):
+    rows = w_data.shape[0] * (CODES_PER_BYTE if packed else 1)
+    assert rows == kdim, (kdim, w_data.shape, packed)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("need_t", "n_max", "block_m", "block_n", "block_k",
-                     "out_dtype", "interpret"))
-def tim_matmul_pallas(x_q: jax.Array, w_q: jax.Array,
+    static_argnames=("packed", "need_t", "n_max", "block_m", "block_n",
+                     "block_k", "out_dtype", "interpret"))
+def tim_matmul_pallas(x_q: jax.Array, w_data: jax.Array,
                       w1: jax.Array, w2: jax.Array, i1: jax.Array,
-                      *, need_t: bool, n_max: Optional[int] = None,
+                      *, packed: bool = False, need_t: bool,
+                      n_max: Optional[int] = None,
                       block_m: int = DEFAULT_BM, block_n: int = DEFAULT_BN,
                       block_k: int = DEFAULT_BK,
                       out_dtype=jnp.float32, interpret: bool = False
                       ) -> jax.Array:
     """Single-phase ternary matmul.  x_q: (M, K) int8 codes (phase-masked
-    upstream if asymmetric inputs), w_q: (K, N) int8 codes, w1/w2: (N,)
-    f32 positive/negative weight scales, i1: scalar input scale.
+    upstream if asymmetric inputs), w_data: (K, N) int8 codes or
+    (K//4, N) uint8 codes packed along K, w1/w2: (N,) f32
+    positive/negative weight scales, i1: scalar input scale.
     """
     m, kdim = x_q.shape
-    k2, n = w_q.shape
-    assert kdim == k2, (x_q.shape, w_q.shape)
+    n = w_data.shape[1]
+    _check_k(kdim, w_data, packed)
     if n_max is not None:
-        block_k = L_BLOCK
         need_t = True
 
-    plan = _tile_plan(x_q, w_q, w1, w2, packed=False, block_m=block_m,
-                      block_n=block_n, block_k=block_k)
+    plan = _tile_plan(x_q, w_data, w1, w2, packed=packed, n_max=n_max,
+                      block_m=block_m, block_n=block_n, block_k=block_k)
     kernel = functools.partial(
         _tim_kernel, nsteps=plan.grid[2], need_t=need_t, n_max=n_max,
-        packed=False, out_dtype=out_dtype)
+        packed=packed, out_dtype=out_dtype)
 
     out = pl.pallas_call(
         kernel,
@@ -265,70 +350,7 @@ def tim_matmul_pallas(x_q: jax.Array, w_q: jax.Array,
         scratch_shapes=_acc_shapes(plan, (True, need_t)),
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(plan.x, plan.w, plan.w1, plan.w2,
-      jnp.reshape(i1, (1,)).astype(jnp.float32))
-    return out[:m, :n]
-
-
-# ---------------------------------------------------------------------------
-# Packed-weight variant: weights arrive 4-codes-per-byte (the TPC's 2-bit
-# storage).  HBM traffic per weight is 2 bits; the unpack happens on the
-# VPU after the (4x smaller) tile is already in VMEM.
-# ---------------------------------------------------------------------------
-
-def _unpack2b_tile(pw):
-    """(bkp, bn) uint8 -> (bkp*4, bn) int8 ternary codes.
-
-    Field encoding per core/packing.py: 00→0, 01→+1, 11→-1.
-    """
-    bkp, bn = pw.shape
-    shifts = jnp.arange(CODES_PER_BYTE, dtype=jnp.uint8) * 2
-    fields = (pw[:, None, :] >> shifts[None, :, None]) & 0b11   # (bkp,4,bn)
-    q = jnp.where(fields == 1, 1, jnp.where(fields == 3, -1, 0))
-    return q.reshape(bkp * CODES_PER_BYTE, bn).astype(jnp.int8)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("need_t", "n_max", "block_m", "block_n", "block_k",
-                     "out_dtype", "interpret"))
-def tim_matmul_packed_pallas(x_q: jax.Array, w_packed: jax.Array,
-                             w1: jax.Array, w2: jax.Array, i1: jax.Array,
-                             *, need_t: bool, n_max: Optional[int] = None,
-                             block_m: int = DEFAULT_BM,
-                             block_n: int = DEFAULT_BN,
-                             block_k: int = DEFAULT_BK,
-                             out_dtype=jnp.float32,
-                             interpret: bool = False) -> jax.Array:
-    """Ternary matmul with 2-bit packed weights.
-
-    x_q: (M, K) int8; w_packed: (K//4, N) uint8 (packed along K, axis 0).
-    ``n_max`` enables the per-L-block ADC clamp: the K grid step drops to
-    L=16 codes (4 packed bytes — 4-code aligned, so the in-VMEM unpack
-    composes with the clamp unchanged).
-    """
-    m, kdim = x_q.shape
-    kp4, n = w_packed.shape
-    assert kp4 * CODES_PER_BYTE == kdim, (x_q.shape, w_packed.shape)
-    if n_max is not None:
-        block_k = L_BLOCK
-        need_t = True
-
-    plan = _tile_plan(x_q, w_packed, w1, w2, packed=True, block_m=block_m,
-                      block_n=block_n, block_k=block_k)
-    kernel = functools.partial(
-        _tim_kernel, nsteps=plan.grid[2], need_t=need_t, n_max=n_max,
-        packed=True, out_dtype=out_dtype)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=plan.grid,
-        in_specs=plan.in_specs,
-        out_specs=plan.out_spec,
-        out_shape=jax.ShapeDtypeStruct(plan.out_shape, out_dtype),
-        scratch_shapes=_acc_shapes(plan, (True, need_t)),
-        compiler_params=_compiler_params(),
-        interpret=interpret,
+        name="tim_matmul",
     )(plan.x, plan.w, plan.w1, plan.w2,
       jnp.reshape(i1, (1,)).astype(jnp.float32))
     return out[:m, :n]
@@ -349,7 +371,8 @@ def _tim_kernel_fused(x_ref, w_ref, w1_ref, w2_ref, i12_ref, o_ref,
     Fig. 5b are derived in-register (pos = max(x, 0), neg = max(-x, 0))
     and each phase's S (and T, for asymmetric weights) partials go to
     their own VMEM accumulator.  The signed combination i1*p1 - i2*p2
-    happens once, in the epilogue.
+    happens once, in the epilogue.  Under the ADC clamp each phase is a
+    separate hardware access, so each is clamped on its own.
     """
     kk = pl.program_id(2)
 
@@ -361,31 +384,15 @@ def _tim_kernel_fused(x_ref, w_ref, w1_ref, w2_ref, i12_ref, o_ref,
             tp_acc[...] = jnp.zeros_like(tp_acc)
             tn_acc[...] = jnp.zeros_like(tn_acc)
 
-    x = x_ref[...]
-    w = _unpack2b_tile(w_ref[...]) if packed else w_ref[...]
-    pos = jnp.maximum(x, 0)
-    neg = jnp.maximum(-x, 0)
-    sp = _dot_i32(pos, w)
-    sn = _dot_i32(neg, w)
-    if need_t:
-        aw = jnp.abs(w)
-        tp = _dot_i32(pos, aw)
-        tn = _dot_i32(neg, aw)
-
-    if n_max is None:
-        sp_acc[...] += sp
-        sn_acc[...] += sn
+    x = x_ref[...].astype(jnp.int32)
+    w = _w_tile(w_ref, packed)
+    aw = jnp.abs(w) if need_t else None
+    for xp, s_acc, t_acc in ((jnp.maximum(x, 0), sp_acc, tp_acc),
+                             (jnp.maximum(-x, 0), sn_acc, tn_acc)):
+        s, t = _st_passes(xp, w, aw, n_max, packed)
+        s_acc[...] += s
         if need_t:
-            tp_acc[...] += tp
-            tn_acc[...] += tn
-    else:
-        # each phase is a separate hardware access: clamp per phase
-        spc, tpc = _clamped_st(sp, tp, n_max)
-        snc, tnc = _clamped_st(sn, tn, n_max)
-        sp_acc[...] += spc
-        tp_acc[...] += tpc
-        sn_acc[...] += snc
-        tn_acc[...] += tnc
+            t_acc[...] += t
 
     @pl.when(kk == nsteps - 1)
     def _done():
@@ -395,14 +402,11 @@ def _tim_kernel_fused(x_ref, w_ref, w1_ref, w2_ref, i12_ref, o_ref,
         i2 = i12_ref[1].astype(jnp.float32)
         tp_fin = tp_acc[...] if need_t else None
         tn_fin = tn_acc[...] if need_t else None
-        # per-phase epilogues cast to out_dtype before the subtraction —
-        # same arithmetic as the two-launch run(pos) - run(neg) path.
-        # (Exactly the same: the only deviation the compiler may
-        # introduce is FMA-contracting the last scale mul into the
-        # subtraction, which single-rounds where two launches rounded
-        # twice — invisible whenever the products are exact.)
-        p1 = _epilogue(sp_acc[...], tp_fin, w1, w2, i1, out_dtype)
-        p2 = _epilogue(sn_acc[...], tn_fin, w1, w2, i2, out_dtype)
+        # the phases combine in f32 and round to out_dtype once — the
+        # fused XLA route's arithmetic (the two-launch route rounds each
+        # phase to out_dtype first, a bf16 ulp of the phase apart)
+        p1 = _epilogue(sp_acc[...], tp_fin, w1, w2, i1, jnp.float32)
+        p2 = _epilogue(sn_acc[...], tn_fin, w1, w2, i2, jnp.float32)
         o_ref[...] = (p1 - p2).astype(out_dtype)
 
 
@@ -428,18 +432,13 @@ def tim_matmul_fused_pallas(x_q: jax.Array, w_data: jax.Array,
     ``i1 * phase(pos) - i2 * phase(neg)`` in a single ``pallas_call``.
     """
     m, kdim = x_q.shape
-    if packed:
-        kp4, n = w_data.shape
-        assert kp4 * CODES_PER_BYTE == kdim, (x_q.shape, w_data.shape)
-    else:
-        k2, n = w_data.shape
-        assert kdim == k2, (x_q.shape, w_data.shape)
+    n = w_data.shape[1]
+    _check_k(kdim, w_data, packed)
     if n_max is not None:
-        block_k = L_BLOCK
         need_t = True
 
-    plan = _tile_plan(x_q, w_data, w1, w2, packed=packed, block_m=block_m,
-                      block_n=block_n, block_k=block_k)
+    plan = _tile_plan(x_q, w_data, w1, w2, packed=packed, n_max=n_max,
+                      block_m=block_m, block_n=block_n, block_k=block_k)
     kernel = functools.partial(
         _tim_kernel_fused, nsteps=plan.grid[2], need_t=need_t, n_max=n_max,
         packed=packed, out_dtype=out_dtype)
@@ -455,6 +454,7 @@ def tim_matmul_fused_pallas(x_q: jax.Array, w_data: jax.Array,
         scratch_shapes=_acc_shapes(plan, (True, need_t, True, need_t)),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="tim_matmul_fused",
     )(plan.x, plan.w, plan.w1, plan.w2, i12)
     return out[:m, :n]
 
@@ -477,16 +477,12 @@ def _tim_kernel_bitserial(x_ref, w_ref, w1_ref, w2_ref, step_ref, o_ref,
         if need_t:
             t_acc[...] = jnp.zeros_like(t_acc)
 
-    x = x_ref[...]                        # unsigned codes < 2**bits
-    w = _unpack2b_tile(w_ref[...]) if packed else w_ref[...]
+    x = x_ref[...].astype(jnp.int32)      # unsigned codes < 2**bits
+    w = _w_tile(w_ref, packed)
     aw = jnp.abs(w) if need_t else None
     for b in range(bits):
-        plane = ((x >> b) & 1).astype(jnp.int8)
-        s = _dot_i32(plane, w)
-        t = _dot_i32(plane, aw) if need_t else None
-        if n_max is not None:
-            # every bit-plane is its own hardware access: clamp per plane
-            s, t = _clamped_st(s, t, n_max)
+        # every bit-plane is its own hardware access: clamped per plane
+        s, t = _st_passes((x >> b) & 1, w, aw, n_max, packed)
         s_acc[...] += s * (1 << b)
         if need_t:
             t_acc[...] += t * (1 << b)
@@ -523,18 +519,14 @@ def tim_matmul_bitserial_fused_pallas(act_codes: jax.Array,
     (folded into the epilogue, like the PCU's final scale).
     """
     m, kdim = act_codes.shape
-    if packed:
-        kp4, n = w_data.shape
-        assert kp4 * CODES_PER_BYTE == kdim, (act_codes.shape, w_data.shape)
-    else:
-        k2, n = w_data.shape
-        assert kdim == k2, (act_codes.shape, w_data.shape)
+    n = w_data.shape[1]
+    _check_k(kdim, w_data, packed)
     if n_max is not None:
-        block_k = L_BLOCK
         need_t = True
 
     plan = _tile_plan(act_codes, w_data, w1, w2, packed=packed,
-                      block_m=block_m, block_n=block_n, block_k=block_k)
+                      n_max=n_max, block_m=block_m, block_n=block_n,
+                      block_k=block_k)
     kernel = functools.partial(
         _tim_kernel_bitserial, nsteps=plan.grid[2], bits=bits,
         need_t=need_t, n_max=n_max, packed=packed, out_dtype=out_dtype)
@@ -548,6 +540,7 @@ def tim_matmul_bitserial_fused_pallas(act_codes: jax.Array,
         scratch_shapes=_acc_shapes(plan, (True, need_t)),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="tim_matmul_bitserial",
     )(plan.x, plan.w, plan.w1, plan.w2,
       jnp.reshape(act_step, (1,)).astype(jnp.float32))
     return out[:m, :n]

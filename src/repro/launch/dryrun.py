@@ -2,9 +2,13 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512"
                            ).strip()
+# CPU-only: the 512 devices are host-platform stand-ins, so this script
+# never touches (or holds) an accelerator, even where one is attached.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # The lines above MUST run before any other import (jax locks the device
-# count at first init).  Pre-existing XLA_FLAGS (user/CI) are preserved —
-# the device-count flag is *appended*.  Everything below is normal code.
+# count and platform at first init).  Pre-existing XLA_FLAGS (user/CI)
+# are preserved — the device-count flag is *appended*.  Everything below
+# is normal code.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production meshes and extract the roofline terms.
@@ -309,8 +313,8 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh,
         step = build_train_step(cfg, grad_compress="gc8" in feats)
         jitted = jax.jit(
             step,
-            in_shardings=shd.as_shardings((p_ps, opt_ps, batch_ps), mesh),
-            out_shardings=shd.as_shardings((p_ps, opt_ps, P()), mesh))
+            in_shardings=(p_ps, opt_ps, batch_ps),
+            out_shardings=(p_ps, opt_ps, P()))
         args = (params_sds, opt_sds, batch_sds)
     else:
         params_sds = param_specs(cfg, serve=True)
@@ -349,8 +353,8 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh,
             step = make_prefill_step(cfg)
             jitted = jax.jit(
                 step,
-                in_shardings=shd.as_shardings((p_ps, batch_ps, c_ps), mesh),
-                out_shardings=shd.as_shardings((bspec, c_ps), mesh))
+                in_shardings=(p_ps, batch_ps, c_ps),
+                out_shardings=(bspec, c_ps))
             args = (params_sds, batch_sds, caches)
         elif shape.kind == "mixed":
             # the serving engine's unified step: a (slots, chunk) token
@@ -399,10 +403,9 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh,
                 step = make_paged_unified_step(cfg)
                 jitted = jax.jit(
                     step,
-                    in_shardings=shd.as_shardings(
-                        (p_ps, batch_ps, c_ps, bspec, bspec, bspec,
-                         bspec), mesh),
-                    out_shardings=shd.as_shardings((bspec, c_ps), mesh))
+                    in_shardings=(p_ps, batch_ps, c_ps, bspec, bspec, bspec,
+                         bspec),
+                    out_shardings=(bspec, c_ps))
                 args = (params_sds, batch_sds, caches, clen, nnew, tbl,
                         smap)
             else:
@@ -412,9 +415,8 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh,
                 step = make_unified_step(cfg)
                 jitted = jax.jit(
                     step,
-                    in_shardings=shd.as_shardings(
-                        (p_ps, batch_ps, c_ps, bspec, bspec), mesh),
-                    out_shardings=shd.as_shardings((bspec, c_ps), mesh))
+                    in_shardings=(p_ps, batch_ps, c_ps, bspec, bspec),
+                    out_shardings=(bspec, c_ps))
                 args = (params_sds, batch_sds, caches, clen, nnew)
         else:
             batch_sds = batch_specs(cfg, shape.global_batch, 1)
@@ -425,12 +427,11 @@ def run_cell(arch: str, shape_name: str, mesh: Mesh,
             step = build_serve_step(cfg)
             jitted = jax.jit(
                 step,
-                in_shardings=shd.as_shardings((p_ps, batch_ps, c_ps, bspec),
-                                              mesh),
-                out_shardings=shd.as_shardings((bspec, c_ps), mesh))
+                in_shardings=(p_ps, batch_ps, c_ps, bspec),
+                out_shardings=(bspec, c_ps))
             args = (params_sds, batch_sds, caches, clen)
 
-    with shd.use_mesh(mesh), shd.sharding_hints(hints):
+    with jax.set_mesh(mesh), shd.sharding_hints(hints):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -214,3 +214,19 @@ def weight_stream_summary(report: Dict[str, int],
         "weight_bytes_streamed_unfused_per_dev": unfused // nd,
         "fused_traffic_ratio": (unfused / fused) if fused else 1.0,
     }
+
+
+_KERNEL_RE = re.compile(r"^\s*(?:ROOT\s+)?%([A-Za-z_][\w\-]*?)(?:\.\d+)?\s*="
+                        r".*custom_call_target=\"tpu_custom_call\"")
+
+
+def tpu_kernel_calls(hlo: str) -> Dict[str, int]:
+    """Mosaic kernel launches in optimized HLO text, counted by kernel
+    name (each ``pallas_call``'s ``name=``), one per call site: a call
+    inside a loop body counts once."""
+    counts: Dict[str, int] = defaultdict(int)
+    for line in hlo.splitlines():
+        m = _KERNEL_RE.match(line)
+        if m:
+            counts[m.group(1)] += 1
+    return dict(counts)

@@ -35,23 +35,29 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import transformer as tfm
-    from repro.serve.engine import Request, ServeEngine, ternarize_model
+    from repro.serve.engine import (Request, ServeEngine, init_serving,
+                                    ternarize_model)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if not cfg.supports_decode:
         raise SystemExit(f"{args.arch} is encoder-only")
     if args.pack:
         cfg = cfg.replace(ternary=cfg.ternary.replace(pack=True))
 
-    params = tfm.init(cfg, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
     if args.ckpt:
         from repro.train.checkpoint import restore_pytree
-        state, step = restore_pytree({"params": params, "opt": None},
-                                     args.ckpt)
-        params = state["params"]
+        state, step = restore_pytree(
+            {"params": tfm.init(cfg, key), "opt": None}, args.ckpt)
         print(f"[serve] loaded checkpoint step {step}")
-    sparams = ternarize_model(params, cfg)
+        sparams = ternarize_model(state["params"], cfg)
+    else:
+        # random weights: codes built period by period, so no fp32
+        # master tree is ever whole on the device
+        sparams = init_serving(cfg, key)
 
     engine = ServeEngine(sparams, cfg, batch_slots=args.slots,
                          max_len=args.max_len, chunk=args.chunk,
@@ -74,7 +80,8 @@ def main():
     dt = time.perf_counter() - t0
     toks = sum(len(r.out_tokens) for r in done)
     print(f"[serve] {len(done)} requests, {toks} tokens, "
-          f"{toks / dt:.1f} tok/s")
+          f"{toks / dt:.1f} tok/s on {jax.devices()[0].device_kind} "
+          f"(wall clock, compile included)")
 
 
 if __name__ == "__main__":

@@ -31,12 +31,14 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.nn.module import param_count
     from repro.train.data import DataConfig
     from repro.train.optimizer import OptConfig, ScheduleConfig
     from repro.train.trainer import TrainConfig, Trainer
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_host_mesh(model=args.model_parallel)
     tcfg = TrainConfig(

@@ -340,7 +340,22 @@ def _block_apply(p, x, cfg: ArchConfig, spec: BlockSpec, positions,
 # full model
 # ---------------------------------------------------------------------------
 
-def init(cfg: ArchConfig, key: jax.Array) -> Params:
+def period_key(key: jax.Array, i: int) -> jax.Array:
+    return subkey(key, f"period{i}")
+
+
+def init_period(cfg: ArchConfig, kp: jax.Array) -> Params:
+    """Master params of one period (one entry of ``init``'s stack) from
+    its ``period_key``."""
+    return {f"b{j}": _block_init(subkey(kp, f"b{j}"), cfg, spec)
+            for j, spec in enumerate(cfg.layout)}
+
+
+def init(cfg: ArchConfig, key: jax.Array,
+         layers: Optional[Params] = None) -> Params:
+    """Master params.  ``layers`` supplies the period stack already
+    built (``serve/engine.init_serving`` converts it period by period);
+    by default every period's masters are built here and stacked."""
     p: Params = {}
     if cfg.frontend_dim:  # audio stub: project precomputed frames
         p["frontend"] = dense_init(subkey(key, "frontend"), cfg.frontend_dim,
@@ -352,14 +367,12 @@ def init(cfg: ArchConfig, key: jax.Array) -> Params:
         p["media_proj"] = dense_init(subkey(key, "media"), cfg.media_dim,
                                      cfg.d_model, dtype=cfg.pdtype)
 
-    def one_period(i):
-        kp = subkey(key, f"period{i}")
-        return {f"b{j}": _block_init(subkey(kp, f"b{j}"), cfg, spec)
-                for j, spec in enumerate(cfg.layout)}
-
-    periods = [one_period(i) for i in range(cfg.n_periods)]
-    p["layers"] = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs, 0), *periods)
+    if layers is None:
+        periods = [init_period(cfg, period_key(key, i))
+                   for i in range(cfg.n_periods)]
+        layers = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs, 0), *periods)
+    p["layers"] = layers
     p["final_norm"] = _norm_init(cfg, cfg.d_model)
     if not cfg.tie_embeddings:
         p["lm_head"] = dense_init(subkey(key, "head"), cfg.d_model,

@@ -69,6 +69,7 @@ admission skips the prefill FLOPs entirely.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -80,7 +81,7 @@ from repro.models import transformer as tfm
 from repro.nn.linear import TernaryPolicy
 from repro.serve.block_pool import (ROOT_HASH, BlockPool, chain_hash,
                                     default_num_blocks)
-from repro.sim.chip import HOST_LINK_BW, PEAK_FLOPS
+from repro.sim.chip import chip_peaks
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +172,34 @@ def _pack_maybe(q, scales, k_dim: int, pol: TernaryPolicy):
         widths[ax] = (0, pad)
         q = jnp.pad(q, widths)
     return TernaryWeight(pack2b(q, axis=ax), scales, True, k_dim)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_period(stack, one, i):
+    return jax.tree_util.tree_map(lambda s, x: s.at[i].set(x), stack, one)
+
+
+def init_serving(cfg: ArchConfig, key: jax.Array) -> Dict[str, Any]:
+    """Serving params from a seed without the whole fp32 master tree:
+    ``ternarize_model(tfm.init(cfg, key), cfg)``, built one period at a
+    time.  Each period's masters become codes before the next period's
+    exist, and the codes land in a preallocated stack (donated, so no
+    second copy), so the device holds the codes, the embedding/head and
+    one period of masters at once."""
+    if not cfg.ternary.enabled:
+        return tfm.init(cfg, key)
+    period_codes = jax.jit(
+        lambda kp: ternarize_model(tfm.init_period(cfg, kp), cfg))
+    layers = None
+    for i in range(cfg.n_periods):
+        codes = period_codes(tfm.period_key(key, i))
+        if layers is None:
+            layers = jax.tree_util.tree_map(
+                lambda x: jnp.zeros((cfg.n_periods,) + x.shape, x.dtype),
+                codes)
+        layers = _put_period(layers, codes, i)
+        del codes
+    return tfm.init(cfg, key, layers=layers)
 
 
 def weight_stream_report(params: Dict[str, Any], cfg: ArchConfig,
@@ -446,13 +475,11 @@ def write_kv_block(caches, dst, values):
 
 _write_kv_block_jit = jax.jit(write_kv_block, donate_argnums=(0,))
 
-# Swap-vs-recompute crossover constants (the roofline estimate):
-# recompute replays the dropped tokens through the model at PEAK_FLOPS;
-# swap round-trips the blocks' KV bytes over the host link.  Imported
-# at the top from repro.sim.chip — the ONE home shared with
-# benchmarks/roofline.py, so the preemption crossover and the roofline
-# model cannot drift apart (re-exported here for callers/tests that
-# patch the engine's view of them).
+# Swap-vs-recompute crossover (the roofline estimate): recompute
+# replays the dropped tokens through the model at the chip's peak
+# FLOP/s; swap round-trips the blocks' KV bytes over the host link.
+# Both come from repro.sim.chip's row for the device the engine runs
+# on — the ONE home shared with benchmarks/roofline.py.
 
 # row-wise update of the device-resident block-table mirror (module
 # scope: one compile per table shape, shared across engines)
@@ -1429,8 +1456,10 @@ class ServeEngine:
         mode = self.preempt
         if mode == "auto":
             own_tokens = min(covered, len(own) * self.block_size)
-            t_recompute = 2.0 * self._n_params * own_tokens / PEAK_FLOPS
-            t_swap = 2.0 * len(own) * self._block_bytes / HOST_LINK_BW
+            chip = chip_peaks()
+            t_recompute = 2.0 * self._n_params * own_tokens \
+                / chip.peak_flops
+            t_swap = 2.0 * len(own) * self._block_bytes / chip.host_link_bw
             mode = "swap" if t_swap < t_recompute else "recompute"
         swap: Dict[int, Any] = {}
         if mode == "swap" and own:
@@ -1649,7 +1678,7 @@ class ServeEngine:
             if self.cfg.n_media_tokens:
                 batch["media"] = self._media_dev
             lg, self.caches = self._step(self.params, batch, self.caches,
-                                         jnp.asarray(self.cache_len),
+                                         jnp.array(self.cache_len),
                                          jnp.asarray(n_new),
                                          self._tables_dev,
                                          jnp.asarray(slot_map))
@@ -1843,7 +1872,7 @@ class ServeEngine:
         else:
             lg, self.caches = self._spec_step(
                 self.params, {"tokens": jnp.asarray(tokens)},
-                self.caches, jnp.asarray(self.cache_len),
+                self.caches, jnp.array(self.cache_len),
                 jnp.asarray(n_new), self._tables_dev,
                 jnp.asarray(slot_map))
             self.grid_tokens += self.slots * self.chunk
@@ -1941,19 +1970,25 @@ class ServeEngine:
     def _sync_device_state(self):
         """Upload whatever host-side state changed since the last step:
         the per-slot media batch and the dirty rows of the device
-        block-table mirror (whole-table refresh when most rows moved)."""
+        block-table mirror (whole-table refresh when most rows moved).
+
+        Host arrays the engine later mutates in place (``cache_len``,
+        ``block_tables``, the media batch) are uploaded with a copy
+        (``jnp.array``): on the CPU backend ``jnp.asarray`` may alias
+        the numpy buffer, and the asynchronously dispatched step would
+        then read values the host has already advanced."""
         if self.cfg.n_media_tokens and self._media_dirty:
-            self._media_dev = jnp.asarray(self._media_host)
+            self._media_dev = jnp.array(self._media_host)
             self._media_dirty = False
         if self._dirty_slots:
             if self._tables_dev is None or \
                     len(self._dirty_slots) > self.slots // 2:
-                self._tables_dev = jnp.asarray(self.block_tables)
+                self._tables_dev = jnp.array(self.block_tables)
             else:
                 for i in sorted(self._dirty_slots):
                     self._tables_dev = self._set_table_row(
                         self._tables_dev, np.int32(i),
-                        jnp.asarray(self.block_tables[i]))
+                        jnp.array(self.block_tables[i]))
             self._dirty_slots.clear()
 
     def _sample_inputs(self, sample_rows: List[int]

@@ -136,7 +136,7 @@ class Trainer:
          (self.opt_init,)) = make_train_step(arch, tcfg, mesh, self.rules)
 
         key = jax.random.PRNGKey(seed)
-        with shd.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.params = jax.jit(
                 lambda k: tfm.init(arch, k),
                 out_shardings=shd.tree_shardings(
@@ -177,7 +177,7 @@ class Trainer:
             ) -> Dict[str, float]:
         num_shards = 1  # single-host data feed; sharded by GSPMD on entry
         history = []
-        with shd.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             while self.step < num_steps:
                 t0 = time.perf_counter()
                 batch = make_batch(self.dcfg, self.arch, self.step,

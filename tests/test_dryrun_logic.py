@@ -117,7 +117,9 @@ def test_paged_gather_pricing_in_roofline_row():
     a 2x KV round trip (copy write + copy read) on top of the memory
     term, the Pallas kernel route pays nothing extra — and both the
     saved bytes and the kernel_bench paged_attn_* ratio agree."""
-    from benchmarks.roofline import HBM_BW, _kv_write_bytes, roofline_row
+    from benchmarks.roofline import _kv_write_bytes, roofline_row
+    from repro.sim.chip import chip_peaks
+    HBM_BW = chip_peaks().hbm_bw
     sc = SHAPES["mixed_32k_shared"]
     cell = {
         "status": "ok", "arch": "granite-34b",
@@ -186,8 +188,9 @@ def test_spec_draft_pricing_in_roofline_row():
     lowered HLO (draft tokens are just extra n_new rows)."""
     import pytest
 
-    from benchmarks.roofline import (PEAK_FLOPS, arch_params,
-                                     roofline_row)
+    from benchmarks.roofline import arch_params, roofline_row
+    from repro.sim.chip import chip_peaks
+    PEAK_FLOPS = chip_peaks().peak_flops
     from repro.kernels.ops import bitserial_pass_ratio
 
     assert bitserial_pass_ratio(2, 4) == 0.5

@@ -302,10 +302,11 @@ def test_fused_out_dtypes(out_dtype, impl):
     got = ops.tim_matmul(qx, tw, sx, impl=impl, fused=True,
                          out_dtype=out_dtype)
     assert got.dtype == out_dtype
-    # bf16 two-phase rounds each phase before subtracting (the fused
-    # xla route rounds once at the end and is strictly more accurate),
-    # so allow a couple of bf16 ulps *of the phase magnitude* — the
-    # pre-cancellation intermediates, not the possibly-tiny result
+    # the unfused two-launch route rounds each bf16 phase before
+    # subtracting (the fused routes round once at the end and are
+    # strictly more accurate), so allow a couple of bf16 ulps *of the
+    # phase magnitude* — the pre-cancellation intermediates, not the
+    # possibly-tiny result
     want = ops.tim_matmul(qx, tw, sx, impl=impl, fused=False,
                           out_dtype=out_dtype)
     want_f32 = np.asarray(want.astype(jnp.float32))

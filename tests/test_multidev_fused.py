@@ -73,7 +73,7 @@ _SCRIPT = textwrap.dedent("""
                                                     fused=True))
     two_fn = jax.jit(lambda q, wt: ops.tim_matmul(q, wt, sx, impl="xla",
                                                   fused=False))
-    with shd.use_mesh(mesh), shd.sharding_hints({"batch": "data"}):
+    with jax.set_mesh(mesh), shd.sharding_hints({"batch": "data"}):
         fused_c = fused_fn.lower(qx_sh, tw_sh).compile()
         two_c = two_fn.lower(qx_sh, tw_sh).compile()
     got_fused = np.asarray(fused_c(qx_sh, tw_sh))
@@ -105,7 +105,7 @@ _SCRIPT = textwrap.dedent("""
             jax.device_put(tw.scales.neg, NamedSharding(mesh, P("model"))),
             False),
         False, tw.k_dim)
-    with shd.use_mesh(mesh), shd.sharding_hints({"batch": "data"}):
+    with jax.set_mesh(mesh), shd.sharding_hints({"batch": "data"}):
         fused_2d = fused_fn.lower(qx_sh, tw_2d).compile()
         two_2d = two_fn.lower(qx_sh, tw_2d).compile()
     got_f2 = np.asarray(fused_2d(qx_sh, tw_2d))
@@ -137,7 +137,7 @@ _SCRIPT = textwrap.dedent("""
             q, s, wt, bits=bits, impl="xla", fused=True))
         bs2_fn = jax.jit(lambda q, s, wt: ops.tim_matmul_bitserial(
             q, s, wt, bits=bits, impl="xla", fused=False))
-        with shd.use_mesh(mesh), shd.sharding_hints({"batch": "data"}):
+        with jax.set_mesh(mesh), shd.sharding_hints({"batch": "data"}):
             bs_c = bs_fn.lower(qa_sh, step, tw_sh).compile()
             got_bs = np.asarray(bs_c(qa_sh, step, tw_sh))
             got_bs2 = np.asarray(bs2_fn(qa_sh, step, tw_sh))

@@ -21,7 +21,7 @@ Covers, in order:
     ``counter_deltas``, the median-window drift detector (sustained
     drift flags, a single spike does not), percentile digests;
   * the chip-constants hoist: engine and roofline read the SAME
-    ``repro.sim.chip`` values.
+    ``repro.sim.chip`` table, keyed by device kind.
 """
 import jax
 import numpy as np
@@ -392,8 +392,10 @@ def test_chip_constants_single_home():
     from benchmarks import roofline
     from repro.serve import engine
     from repro.sim import chip
-    assert engine.PEAK_FLOPS is chip.PEAK_FLOPS
-    assert engine.HOST_LINK_BW is chip.HOST_LINK_BW
-    assert roofline.PEAK_FLOPS is chip.PEAK_FLOPS
-    assert roofline.HBM_BW is chip.HBM_BW
-    assert roofline.LINK_BW is chip.LINK_BW
+    assert engine.chip_peaks is chip.chip_peaks
+    assert roofline.chip_peaks is chip.chip_peaks
+    # the CPU backend prices against the modelled chip, by name
+    assert chip.chip_peaks() is chip.CHIPS[chip.MODELLED_KIND]
+    assert chip.chip_peaks().kind == "TPU v5 lite"
+    with pytest.raises(KeyError, match="TPU v9"):
+        chip.chip_peaks("TPU v9")
