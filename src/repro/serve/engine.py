@@ -70,11 +70,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tfm
@@ -709,6 +711,13 @@ class Request:
     # these; -1 / empty until the events happen.
     submit_step: int = -1
     token_steps: List[int] = dataclasses.field(default_factory=list)
+    # the same lifecycle on the host clock (time.perf_counter(), the
+    # clock a caller stamps delivered tokens with): submission, first
+    # admission to a slot (a resume after preemption keeps it) and the
+    # first output token; None until the event happens
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
     # parallel sampling: submit with n > 1 and the engine expands the
     # request into n sibling sequences sharing the same uid (and all
     # full prompt blocks, by refcount — ONE prefill serves all n).
@@ -736,6 +745,13 @@ class Request:
     def first_token_step(self) -> int:
         """Step index of the first emitted token (-1 before it exists)."""
         return self.token_steps[0] if self.token_steps else -1
+
+
+def _stamp_first(reqs: List[Request], now: float) -> None:
+    """Stamp ``t_first`` on each request that got its first token."""
+    for r in reqs:
+        if r.t_first is None and r.out_tokens:
+            r.t_first = now
 
 
 class ServeEngine:
@@ -788,9 +804,12 @@ class ServeEngine:
     clock (``iters``, +1 per ``step()`` call): ``Request.submit_step``
     and ``Request.token_steps`` record when the request arrived and at
     which step each output token was emitted — serve/metrics.py turns
-    these into TTFT/TPOT/goodput digests, and ``stats()`` exposes the
-    cumulative counters (plus occupancy gauges) a per-step telemetry
-    stream diffs (docs/serving.md §telemetry).
+    these into TTFT/TPOT/goodput digests; ``Request.t_submit`` /
+    ``t_admit`` / ``t_first`` stamp the same lifecycle on the host
+    clock, each phase of ``step()`` runs under a profiler span, and
+    ``stats()`` exposes the cumulative counters (plus occupancy
+    gauges) a per-step telemetry stream diffs (docs/serving.md
+    §telemetry).
 
     Scheduler state is host-side numpy; the only device->host transfer
     per step is the explicit fetch of the sampled tokens
@@ -919,6 +938,9 @@ class ServeEngine:
         self.prefix_hit_tokens = 0
         self.scheduled_prefill_tokens = 0
         self.scheduled_tokens = 0
+        # _schedule() calls that left prompt tokens waiting because the
+        # token budget ran out (a chunk-limited slice does not count)
+        self.budget_full_steps = 0
         # device-grid rows actually launched (padded: slots*chunk per
         # step; packed: the power-of-two token bucket) — the
         # denominator of metrics.summarize()'s padding_efficiency
@@ -1068,6 +1090,7 @@ class ServeEngine:
                     f"beam width {req.n} exceeds batch_slots="
                     f"{self.slots}: every live hypothesis needs a slot "
                     f"for synchronized expansion")
+        req.t_submit = time.perf_counter()   # siblings inherit it
         if req.n > 1:
             # expand into n sibling sequences sharing the uid; the
             # parent itself never enters the queue — callers read
@@ -1280,6 +1303,8 @@ class ServeEngine:
             if self.pool.blocks_free < 1 and self._active_slots():
                 break     # wait for a block instead of thrashing; FIFO
             req = self.queue.pop(0)
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
             if res is not None:
                 del self._resume[(req.uid, req.sample_index)]
                 tokens_in = res["prompt"]     # <= max_len by invariant
@@ -1557,6 +1582,7 @@ class ServeEngine:
             slot_map[i, :t] = blk * self.block_size + pos % self.block_size
 
         budget = self.token_budget
+        starved = False     # a prompt left waiting by the budget alone
         for i in self._active_slots():
             if self.slot_req[i] is None:
                 continue            # preempted earlier in this pass
@@ -1574,7 +1600,10 @@ class ServeEngine:
                 continue            # preempted by a later decode pass
             plen = len(self.slot_prompt[i])
             fill = int(self.slot_fill[i])
-            if fill >= plen or budget <= 0:
+            if fill >= plen:
+                continue
+            starved |= budget < min(self.chunk, plen - fill)
+            if budget <= 0:
                 continue
             take = min(self.chunk, plen - fill, budget)
             cl = int(self.cache_len[i])
@@ -1593,6 +1622,7 @@ class ServeEngine:
             budget -= take
             if fill + take >= plen:
                 finishing_prefill.append(i)
+        self.budget_full_steps += starved
         return tokens, n_new, slot_map, decode_slots, finishing_prefill
 
     def _release_slot(self, i: int):
@@ -1650,114 +1680,149 @@ class ServeEngine:
         including no-op iterations where nothing could be scheduled —
         so lifecycle stamps (``Request.submit_step`` /
         ``token_steps``) live on one monotone step axis.
+
+        Each phase runs under a ``jax.profiler.TraceAnnotation``, so a
+        profiler trace puts every host second (and every device-idle
+        gap) of the call down to one: ``serve.step`` around the call;
+        inside it ``serve.admit``, ``serve.schedule``, ``serve.upload``
+        (host->device state and inputs), ``serve.launch`` (one per
+        dispatch: the step, then the sampler), ``serve.account`` (host
+        bookkeeping while the device runs), ``serve.fetch`` (the one
+        blocking fetch) and ``serve.emit``.  With the profiler off a
+        span costs about a microsecond.
         """
-        this_step = self.iters
-        self.iters += 1
-        self._admit()
-        tokens, n_new, slot_map, decode_slots, finishing = self._schedule()
-        if not n_new.any():
-            return
-        if self.spec_k:
-            self._step_spec(this_step, tokens, n_new, slot_map,
-                            decode_slots, finishing)
-            return
-        self._sync_device_state()
-        if self.packed:
-            (flat, seg, pos, nn, smap, last_idx, bucket) = \
-                self._flatten_grid(tokens, n_new, slot_map)
-            batch = {"tokens": jnp.asarray(flat)}
-            if self.cfg.n_media_tokens:
-                batch["media"] = self._media_dev
-            lg, self.caches = self._step(
-                self.params, batch, self.caches, jnp.asarray(pos),
-                jnp.asarray(nn), jnp.asarray(seg), self._tables_dev,
-                jnp.asarray(smap), jnp.asarray(last_idx))
-            self.grid_tokens += bucket
-        else:
-            batch = {"tokens": jnp.asarray(tokens)}
-            if self.cfg.n_media_tokens:
-                batch["media"] = self._media_dev
-            lg, self.caches = self._step(self.params, batch, self.caches,
-                                         jnp.array(self.cache_len),
-                                         jnp.asarray(n_new),
-                                         self._tables_dev,
-                                         jnp.asarray(slot_map))
-            self.grid_tokens += self.slots * self.chunk
-        # host-side bookkeeping: lengths advance by exactly what was
-        # scheduled — no device round-trip
-        old_len = self.cache_len.copy()
-        self.cache_len += n_new
-        self.scheduled_tokens += int(n_new.sum())
-        self._last_slot_map = np.where(
-            np.arange(self.chunk)[None, :] < n_new[:, None], slot_map, -1)
-        for i in range(self.slots):
-            t = int(n_new[i])
-            if not t:
-                continue
-            if i not in decode_slots:
-                self.slot_fill[i] += t               # prompt cursor
-                self.scheduled_prefill_tokens += t
-            self.slot_hist[i].extend(int(x) for x in tokens[i, :t])
-            if self.prefix_reuse:
-                self._register_completed(i, int(old_len[i]),
-                                         int(old_len[i]) + t)
-        # rows that consume a token this step (token_index for the
-        # per-request PRNG stream is len(out_tokens) BEFORE any append)
-        sample_rows = decode_slots + [i for i in finishing
-                                      if not self._skip_sample[i]]
-        beam_rows = [i for i in sample_rows
-                     if self.slot_req[i].sample_mode == "beam"]
-        use_sampler = ((not self.greedy) or bool(beam_rows) or any(
-            self.slot_req[i].allowed_tokens is not None
-            for i in sample_rows))
-        cand_ids = cand_lps = None
-        if not use_sampler:
-            out_dev = greedy_token(lg)
-        else:
-            ids, mask = self._sample_inputs(sample_rows)
-            topk = max((self.slot_req[i].n for i in beam_rows),
-                       default=0)
-            sampler = _get_sampler(
-                0.0 if self.greedy else self.temperature, topk)
-            out_dev = sampler(lg, self._base_key, jnp.asarray(ids),
-                              jnp.asarray(mask))
-        # timcheck: allow[d2h] the ONE accounted fetch per step (d2h_fetches)
-        fetched = jax.device_get(out_dev)             # the ONE d2h fetch
-        self.d2h_fetches += 1
-        if isinstance(fetched, tuple):
-            toks, cand_ids, cand_lps = (np.asarray(a) for a in fetched)
-        else:
-            toks = np.asarray(fetched)
-        beam_decode = [i for i in decode_slots if i in beam_rows]
-        for i in decode_slots:
-            if i in beam_decode:
-                continue
-            req = self.slot_req[i]
-            req.out_tokens.append(int(toks[i]))
-            req.token_steps.append(this_step)
-            self._finish_check(i)
-        if beam_decode:
-            self._beam_decode(beam_decode, cand_ids, cand_lps, this_step)
-        for i in finishing:
-            if self._skip_sample[i]:
-                # resumed-mid-decode refill: the "first generated"
-                # token already exists — out_tokens[-1] is the pending
-                # decode input; appending the (greedy-identical)
-                # re-sample would duplicate it
-                self._skip_sample[i] = False
-                continue
-            req = self.slot_req[i]
-            if req.sample_mode == "beam":
-                # beam root expansion: sibling s seeds its hypothesis
-                # with the s-th best first token (identical prompt =>
-                # identical logits across siblings, so this IS the
-                # joint top-n of the root)
-                req.out_tokens.append(int(cand_ids[i, req.sample_index]))
-                req.cum_logprob += float(cand_lps[i, req.sample_index])
+        with _span("serve.step"):
+            this_step = self.iters
+            self.iters += 1
+            with _span("serve.admit"):
+                self._admit()
+            with _span("serve.schedule"):
+                tokens, n_new, slot_map, decode_slots, finishing = \
+                    self._schedule()
+            if not n_new.any():
+                return
+            if self.spec_k:
+                self._step_spec(this_step, tokens, n_new, slot_map,
+                                decode_slots, finishing)
             else:
-                req.out_tokens.append(int(toks[i]))  # first generated
-            req.token_steps.append(this_step)
-            self._finish_check(i)
+                self._step_plain(this_step, tokens, n_new, slot_map,
+                                 decode_slots, finishing)
+
+    def _step_plain(self, this_step: int, tokens: np.ndarray,
+                    n_new: np.ndarray, slot_map: np.ndarray,
+                    decode_slots: List[int], finishing: List[int]):
+        """The non-speculative tail of ``step()``: upload, launch the
+        step, account, launch the sampler, fetch, emit."""
+        if self.packed:
+            with _span("serve.schedule"):
+                (flat, seg, pos, nn, smap, last_idx, bucket) = \
+                    self._flatten_grid(tokens, n_new, slot_map)
+        with _span("serve.upload"):
+            self._sync_device_state()
+            if self.packed:
+                batch = {"tokens": jnp.asarray(flat)}
+                sched = (jnp.asarray(pos), jnp.asarray(nn),
+                         jnp.asarray(seg), self._tables_dev,
+                         jnp.asarray(smap), jnp.asarray(last_idx))
+            else:
+                bucket = self.slots * self.chunk
+                batch = {"tokens": jnp.asarray(tokens)}
+                sched = (jnp.asarray(self.cache_len.copy()),
+                         jnp.asarray(n_new),
+                         self._tables_dev, jnp.asarray(slot_map))
+            if self.cfg.n_media_tokens:
+                batch["media"] = self._media_dev
+        with _span("serve.launch"):
+            lg, self.caches = self._step(self.params, batch, self.caches,
+                                         *sched)
+        with _span("serve.account"):
+            self.grid_tokens += bucket
+            # host-side bookkeeping: lengths advance by exactly what
+            # was scheduled — no device round-trip
+            old_len = self.cache_len.copy()
+            self.cache_len += n_new
+            self.scheduled_tokens += int(n_new.sum())
+            self._last_slot_map = np.where(
+                np.arange(self.chunk)[None, :] < n_new[:, None],
+                slot_map, -1)
+            for i in range(self.slots):
+                t = int(n_new[i])
+                if not t:
+                    continue
+                if i not in decode_slots:
+                    self.slot_fill[i] += t               # prompt cursor
+                    self.scheduled_prefill_tokens += t
+                self.slot_hist[i].extend(int(x) for x in tokens[i, :t])
+                if self.prefix_reuse:
+                    self._register_completed(i, int(old_len[i]),
+                                             int(old_len[i]) + t)
+            # rows that consume a token this step (token_index for the
+            # per-request PRNG stream is len(out_tokens) BEFORE any
+            # append)
+            sample_rows = decode_slots + [i for i in finishing
+                                          if not self._skip_sample[i]]
+            sampled = [self.slot_req[i] for i in sample_rows]
+            beam_rows = [i for i in sample_rows
+                         if self.slot_req[i].sample_mode == "beam"]
+            use_sampler = ((not self.greedy) or bool(beam_rows) or any(
+                r.allowed_tokens is not None for r in sampled))
+        if use_sampler:
+            with _span("serve.upload"):
+                ids, mask = self._sample_inputs(sample_rows)
+                topk = max((self.slot_req[i].n for i in beam_rows),
+                           default=0)
+                sampler = _get_sampler(
+                    0.0 if self.greedy else self.temperature, topk)
+                ids, mask = jnp.asarray(ids), jnp.asarray(mask)
+            with _span("serve.launch"):
+                out_dev = sampler(lg, self._base_key, ids, mask)
+        else:
+            with _span("serve.launch"):
+                out_dev = greedy_token(lg)
+        with _span("serve.fetch"):
+            # timcheck: allow[d2h] the ONE accounted fetch per step (d2h_fetches)
+            fetched = jax.device_get(out_dev)         # the ONE d2h fetch
+            self.d2h_fetches += 1
+        with _span("serve.emit"):
+            now = time.perf_counter()
+            cand_ids = cand_lps = None
+            if isinstance(fetched, tuple):
+                toks, cand_ids, cand_lps = (np.asarray(a) for a in fetched)
+            else:
+                toks = np.asarray(fetched)
+            beam_decode = [i for i in decode_slots if i in beam_rows]
+            for i in decode_slots:
+                if i in beam_decode:
+                    continue
+                req = self.slot_req[i]
+                req.out_tokens.append(int(toks[i]))
+                req.token_steps.append(this_step)
+                self._finish_check(i)
+            if beam_decode:
+                self._beam_decode(beam_decode, cand_ids, cand_lps,
+                                  this_step)
+            for i in finishing:
+                if self._skip_sample[i]:
+                    # resumed-mid-decode refill: the "first generated"
+                    # token already exists — out_tokens[-1] is the
+                    # pending decode input; appending the
+                    # (greedy-identical) re-sample would duplicate it
+                    self._skip_sample[i] = False
+                    continue
+                req = self.slot_req[i]
+                if req.sample_mode == "beam":
+                    # beam root expansion: sibling s seeds its
+                    # hypothesis with the s-th best first token
+                    # (identical prompt => identical logits across
+                    # siblings, so this IS the joint top-n of the root)
+                    req.out_tokens.append(
+                        int(cand_ids[i, req.sample_index]))
+                    req.cum_logprob += float(cand_lps[i, req.sample_index])
+                else:
+                    req.out_tokens.append(int(toks[i]))  # first generated
+                req.token_steps.append(this_step)
+                self._finish_check(i)
+            _stamp_first(sampled, now)
 
     def _step_spec(self, this_step: int, tokens: np.ndarray,
                    n_new: np.ndarray, slot_map: np.ndarray,
@@ -1782,173 +1847,188 @@ class ServeEngine:
         oob = self.pool.num_blocks * self.block_size
         bs = self.block_size
         # -- plan: grant draft extensions from the leftover budget ----------
-        leftover = max(0, self.token_budget - int(n_new.sum()))
-        k_of: Dict[int, int] = {}
-        for i in decode_slots:
-            if leftover <= 0:
-                break
-            req = self.slot_req[i]
-            cl = int(self.cache_len[i])
-            k = min(self.spec_k, self.chunk - 1, leftover,
-                    self.max_len - 1 - cl,
-                    req.max_new_tokens - len(req.out_tokens) - 1)
-            if k <= 0:
-                continue
-            # grow the table WITHOUT preemption — speculation is an
-            # optimization, never worth evicting anyone; shrink k to
-            # the blocks actually obtained
-            while int(self.slot_nblocks[i]) * bs < cl + 1 + k:
-                bid = self._alloc_block()
-                if bid is None:
+        with _span("serve.schedule"):
+            leftover = max(0, self.token_budget - int(n_new.sum()))
+            k_of: Dict[int, int] = {}
+            for i in decode_slots:
+                if leftover <= 0:
                     break
-                self.block_tables[i, self.slot_nblocks[i]] = bid
-                self.slot_nblocks[i] += 1
-                self._dirty_slots.add(i)
-            k = min(k, int(self.slot_nblocks[i]) * bs - cl - 1)
-            if k <= 0:
-                continue
-            pos = cl + 1 + np.arange(k)
-            blk = self.block_tables[i, pos // bs]
-            slot_map[i, 1:1 + k] = blk * bs + pos % bs
-            n_new[i] = 1 + k
-            k_of[i] = k
-            leftover -= k
-        self._sync_device_state()
+                req = self.slot_req[i]
+                cl = int(self.cache_len[i])
+                k = min(self.spec_k, self.chunk - 1, leftover,
+                        self.max_len - 1 - cl,
+                        req.max_new_tokens - len(req.out_tokens) - 1)
+                if k <= 0:
+                    continue
+                # grow the table WITHOUT preemption — speculation is an
+                # optimization, never worth evicting anyone; shrink k to
+                # the blocks actually obtained
+                while int(self.slot_nblocks[i]) * bs < cl + 1 + k:
+                    bid = self._alloc_block()
+                    if bid is None:
+                        break
+                    self.block_tables[i, self.slot_nblocks[i]] = bid
+                    self.slot_nblocks[i] += 1
+                    self._dirty_slots.add(i)
+                k = min(k, int(self.slot_nblocks[i]) * bs - cl - 1)
+                if k <= 0:
+                    continue
+                pos = cl + 1 + np.arange(k)
+                blk = self.block_tables[i, pos // bs]
+                slot_map[i, 1:1 + k] = blk * bs + pos % bs
+                n_new[i] = 1 + k
+                k_of[i] = k
+                leftover -= k
         # -- sample-row operands: mask row j constrains emission j ----------
-        sample_rows = decode_slots + [i for i in finishing
-                                      if not self._skip_sample[i]]
-        ids = np.zeros((self.slots, 3), np.uint32)
-        masks = np.full((self.slots, self.chunk, self.mask_width), -1,
-                        np.int32)
-        had_mask = np.zeros((self.slots, self.chunk), bool)
-        for i in sample_rows:
-            req = self.slot_req[i]
-            ids[i] = (req.uid, req.sample_index, len(req.out_tokens))
-            row = self._mask_row(req, req.out_tokens)
-            if row is not None:
-                masks[i, 0, :len(row)] = row
-                had_mask[i, 0] = True
+        with _span("serve.upload"):
+            self._sync_device_state()
+            sample_rows = decode_slots + [i for i in finishing
+                                          if not self._skip_sample[i]]
+            sampled = [self.slot_req[i] for i in sample_rows]
+            ids = np.zeros((self.slots, 3), np.uint32)
+            masks = np.full((self.slots, self.chunk, self.mask_width), -1,
+                            np.int32)
+            had_mask = np.zeros((self.slots, self.chunk), bool)
+            for i in sample_rows:
+                req = self.slot_req[i]
+                ids[i] = (req.uid, req.sample_index, len(req.out_tokens))
+                row = self._mask_row(req, req.out_tokens)
+                if row is not None:
+                    masks[i, 0, :len(row)] = row
+                    had_mask[i, 0] = True
         # -- draft loop: k cheap-encoding passes propose the tokens ---------
         # (pass j consumes grid token j and proposes token j+1 under
         # emission j's mask, so a masked token can never be proposed)
-        max_k = max(k_of.values(), default=0)
-        for j in range(max_k):
-            active = [i for i, k in k_of.items() if k > j]
-            d_tok = np.zeros((self.slots, 1), np.int32)
-            d_cl = np.zeros((self.slots,), np.int32)
-            d_nn = np.zeros((self.slots,), np.int32)
-            d_map = np.full((self.slots, 1), oob, np.int32)
-            for i in active:
-                d_tok[i, 0] = tokens[i, j]
-                d_cl[i] = int(self.cache_len[i]) + j
-                d_nn[i] = 1
-                d_map[i, 0] = slot_map[i, j]
-            toks_d, self.caches = self._draft_step(
-                self.params, {"tokens": jnp.asarray(d_tok)}, self.caches,
-                jnp.asarray(d_cl), jnp.asarray(d_nn), self._tables_dev,
-                jnp.asarray(d_map), jnp.asarray(masks[:, j]))
-            # timcheck: allow[d2h] accounted draft fetch (draft_d2h_fetches)
-            d_host = jax.device_get(toks_d)
-            self.draft_d2h_fetches += 1
-            for i in active:
-                tokens[i, 1 + j] = int(d_host[i])
-                req = self.slot_req[i]
-                row = self._mask_row(
-                    req, list(req.out_tokens)
-                    + [int(t) for t in tokens[i, 1:2 + j]])
-                if row is not None:
-                    masks[i, j + 1, :len(row)] = row
-                    had_mask[i, j + 1] = True
+        with _span("serve.draft"):
+            max_k = max(k_of.values(), default=0)
+            for j in range(max_k):
+                active = [i for i, k in k_of.items() if k > j]
+                d_tok = np.zeros((self.slots, 1), np.int32)
+                d_cl = np.zeros((self.slots,), np.int32)
+                d_nn = np.zeros((self.slots,), np.int32)
+                d_map = np.full((self.slots, 1), oob, np.int32)
+                for i in active:
+                    d_tok[i, 0] = tokens[i, j]
+                    d_cl[i] = int(self.cache_len[i]) + j
+                    d_nn[i] = 1
+                    d_map[i, 0] = slot_map[i, j]
+                toks_d, self.caches = self._draft_step(
+                    self.params, {"tokens": jnp.asarray(d_tok)},
+                    self.caches, jnp.asarray(d_cl), jnp.asarray(d_nn),
+                    self._tables_dev, jnp.asarray(d_map),
+                    jnp.asarray(masks[:, j]))
+                # timcheck: allow[d2h] accounted draft fetch (draft_d2h_fetches)
+                d_host = jax.device_get(toks_d)
+                self.draft_d2h_fetches += 1
+                for i in active:
+                    tokens[i, 1 + j] = int(d_host[i])
+                    req = self.slot_req[i]
+                    row = self._mask_row(
+                        req, list(req.out_tokens)
+                        + [int(t) for t in tokens[i, 1:2 + j]])
+                    if row is not None:
+                        masks[i, j + 1, :len(row)] = row
+                        had_mask[i, j + 1] = True
         # -- verify: ONE mixed step over all k+1 positions per slot ---------
-        if self.packed:
-            (flat, seg, pos, nn_, smap, row_idx, bucket) = \
-                self._flatten_spec_grid(tokens, n_new, slot_map)
-            lg, self.caches = self._spec_step(
-                self.params, {"tokens": jnp.asarray(flat)}, self.caches,
-                jnp.asarray(pos), jnp.asarray(nn_), jnp.asarray(seg),
-                self._tables_dev, jnp.asarray(smap),
-                jnp.asarray(row_idx))
+        with _span("serve.upload"):
+            if self.packed:
+                (flat, seg, pos, nn_, smap, row_idx, bucket) = \
+                    self._flatten_spec_grid(tokens, n_new, slot_map)
+                batch = {"tokens": jnp.asarray(flat)}
+                sched = (jnp.asarray(pos), jnp.asarray(nn_),
+                         jnp.asarray(seg), self._tables_dev,
+                         jnp.asarray(smap), jnp.asarray(row_idx))
+            else:
+                bucket = self.slots * self.chunk
+                batch = {"tokens": jnp.asarray(tokens)}
+                sched = (jnp.asarray(self.cache_len.copy()),
+                         jnp.asarray(n_new),
+                         self._tables_dev, jnp.asarray(slot_map))
+            start = np.zeros((self.slots,), np.int32)
+            n_draft = np.zeros((self.slots,), np.int32)
+            for i in range(self.slots):
+                if i in decode_slots:
+                    n_draft[i] = k_of.get(i, 0)
+                elif n_new[i]:
+                    start[i] = int(n_new[i]) - 1
+            accept_in = (jnp.asarray(tokens), jnp.asarray(start),
+                         jnp.asarray(n_draft), self._base_key,
+                         jnp.asarray(ids), jnp.asarray(masks))
             self.grid_tokens += bucket
-        else:
-            lg, self.caches = self._spec_step(
-                self.params, {"tokens": jnp.asarray(tokens)},
-                self.caches, jnp.array(self.cache_len),
-                jnp.asarray(n_new), self._tables_dev,
-                jnp.asarray(slot_map))
-            self.grid_tokens += self.slots * self.chunk
-        start = np.zeros((self.slots,), np.int32)
-        n_draft = np.zeros((self.slots,), np.int32)
-        for i in range(self.slots):
-            if i in decode_slots:
-                n_draft[i] = k_of.get(i, 0)
-            elif n_new[i]:
-                start[i] = int(n_new[i]) - 1
-        out_dev = self._accept(lg, jnp.asarray(tokens),
-                               jnp.asarray(start), jnp.asarray(n_draft),
-                               self._base_key, jnp.asarray(ids),
-                               jnp.asarray(masks))
-        # timcheck: allow[d2h] the ONE accounted fetch per step (d2h_fetches)
-        fetched = jax.device_get(out_dev)
-        self.d2h_fetches += 1
-        emitted, n_emit = (np.asarray(a) for a in fetched)
-        # -- host bookkeeping: prefill rows exactly as the plain step -------
-        old_len = self.cache_len.copy()
-        self.scheduled_tokens += int(n_new.sum())
-        self._last_slot_map = np.where(
-            np.arange(self.chunk)[None, :] < n_new[:, None], slot_map, -1)
-        for i in range(self.slots):
-            t = int(n_new[i])
-            if not t or i in decode_slots:
-                continue
-            self.cache_len[i] += t
-            self.slot_fill[i] += t
-            self.scheduled_prefill_tokens += t
-            self.slot_hist[i].extend(int(x) for x in tokens[i, :t])
-            if self.prefix_reuse:
-                self._register_completed(i, int(old_len[i]),
-                                         int(old_len[i]) + t)
-        # -- decode rows: acceptance accounting, rollback, emission ---------
-        for i in decode_slots:
-            req = self.slot_req[i]
-            k = k_of.get(i, 0)
-            a = int(n_emit[i]) - 1
-            assert 0 <= a <= k, (a, k)
-            self.draft_tokens += k
-            self.accepted_tokens += a
-            self.rejected_tokens += k - a
-            if k and a == k:
-                self.bonus_tokens += 1
-            new_cl = int(old_len[i]) + 1 + a
-            self.cache_len[i] = new_cl
-            self.slot_hist[i].append(int(tokens[i, 0]))
-            self.slot_hist[i].extend(int(emitted[i, j]) for j in range(a))
-            # rollback: release speculative tail blocks beyond the
-            # accepted coverage (cache_len already retreated past them)
-            need = -(-new_cl // bs)
-            while int(self.slot_nblocks[i]) > need:
-                nb = int(self.slot_nblocks[i]) - 1
-                self.pool.decref(int(self.block_tables[i, nb]))
-                self.block_tables[i, nb] = -1
-                self.slot_nblocks[i] = nb
-                self._dirty_slots.add(i)
-            if self.prefix_reuse:
-                self._register_completed(i, int(old_len[i]), new_cl)
-            for j in range(a + 1):
-                if had_mask[i, j]:
+        with _span("serve.launch"):
+            lg, self.caches = self._spec_step(self.params, batch,
+                                              self.caches, *sched)
+        with _span("serve.launch"):
+            out_dev = self._accept(lg, *accept_in)
+        with _span("serve.fetch"):
+            # timcheck: allow[d2h] the ONE accounted fetch per step (d2h_fetches)
+            fetched = jax.device_get(out_dev)
+            self.d2h_fetches += 1
+        with _span("serve.emit"):
+            now = time.perf_counter()
+            emitted, n_emit = (np.asarray(a) for a in fetched)
+            # -- host bookkeeping: prefill rows exactly as the plain step
+            old_len = self.cache_len.copy()
+            self.scheduled_tokens += int(n_new.sum())
+            self._last_slot_map = np.where(
+                np.arange(self.chunk)[None, :] < n_new[:, None],
+                slot_map, -1)
+            for i in range(self.slots):
+                t = int(n_new[i])
+                if not t or i in decode_slots:
+                    continue
+                self.cache_len[i] += t
+                self.slot_fill[i] += t
+                self.scheduled_prefill_tokens += t
+                self.slot_hist[i].extend(int(x) for x in tokens[i, :t])
+                if self.prefix_reuse:
+                    self._register_completed(i, int(old_len[i]),
+                                             int(old_len[i]) + t)
+            # -- decode rows: acceptance accounting, rollback, emission
+            for i in decode_slots:
+                req = self.slot_req[i]
+                k = k_of.get(i, 0)
+                a = int(n_emit[i]) - 1
+                assert 0 <= a <= k, (a, k)
+                self.draft_tokens += k
+                self.accepted_tokens += a
+                self.rejected_tokens += k - a
+                if k and a == k:
+                    self.bonus_tokens += 1
+                new_cl = int(old_len[i]) + 1 + a
+                self.cache_len[i] = new_cl
+                self.slot_hist[i].append(int(tokens[i, 0]))
+                self.slot_hist[i].extend(int(emitted[i, j])
+                                         for j in range(a))
+                # rollback: release speculative tail blocks beyond the
+                # accepted coverage (cache_len already retreated past
+                # them)
+                need = -(-new_cl // bs)
+                while int(self.slot_nblocks[i]) > need:
+                    nb = int(self.slot_nblocks[i]) - 1
+                    self.pool.decref(int(self.block_tables[i, nb]))
+                    self.block_tables[i, nb] = -1
+                    self.slot_nblocks[i] = nb
+                    self._dirty_slots.add(i)
+                if self.prefix_reuse:
+                    self._register_completed(i, int(old_len[i]), new_cl)
+                for j in range(a + 1):
+                    if had_mask[i, j]:
+                        self.masked_tokens += 1
+                    req.out_tokens.append(int(emitted[i, j]))
+                    req.token_steps.append(this_step)
+                self._finish_check(i)
+            for i in finishing:
+                if self._skip_sample[i]:
+                    self._skip_sample[i] = False
+                    continue
+                req = self.slot_req[i]
+                if had_mask[i, 0]:
                     self.masked_tokens += 1
-                req.out_tokens.append(int(emitted[i, j]))
+                req.out_tokens.append(int(emitted[i, 0]))
                 req.token_steps.append(this_step)
-            self._finish_check(i)
-        for i in finishing:
-            if self._skip_sample[i]:
-                self._skip_sample[i] = False
-                continue
-            req = self.slot_req[i]
-            if had_mask[i, 0]:
-                self.masked_tokens += 1
-            req.out_tokens.append(int(emitted[i, 0]))
-            req.token_steps.append(this_step)
-            self._finish_check(i)
+                self._finish_check(i)
+            _stamp_first(sampled, now)
 
     def _flatten_spec_grid(self, tokens: np.ndarray, n_new: np.ndarray,
                            slot_map: np.ndarray):
@@ -1973,22 +2053,24 @@ class ServeEngine:
         block-table mirror (whole-table refresh when most rows moved).
 
         Host arrays the engine later mutates in place (``cache_len``,
-        ``block_tables``, the media batch) are uploaded with a copy
-        (``jnp.array``): on the CPU backend ``jnp.asarray`` may alias
-        the numpy buffer, and the asynchronously dispatched step would
-        then read values the host has already advanced."""
+        ``block_tables``, the media batch) are uploaded from a fresh
+        numpy copy that nothing else holds: the transfer may read its
+        host buffer after the call returns (the CPU backend may alias
+        it; ``jnp.array`` of a numpy array takes no copy first), and the
+        asynchronously dispatched step would then read values the host
+        has already advanced."""
         if self.cfg.n_media_tokens and self._media_dirty:
-            self._media_dev = jnp.array(self._media_host)
+            self._media_dev = jnp.asarray(self._media_host.copy())
             self._media_dirty = False
         if self._dirty_slots:
             if self._tables_dev is None or \
                     len(self._dirty_slots) > self.slots // 2:
-                self._tables_dev = jnp.array(self.block_tables)
+                self._tables_dev = jnp.asarray(self.block_tables.copy())
             else:
                 for i in sorted(self._dirty_slots):
                     self._tables_dev = self._set_table_row(
                         self._tables_dev, np.int32(i),
-                        jnp.array(self.block_tables[i]))
+                        jnp.asarray(self.block_tables[i].copy()))
             self._dirty_slots.clear()
 
     def _sample_inputs(self, sample_rows: List[int]
@@ -2302,6 +2384,7 @@ class ServeEngine:
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "scheduled_tokens": self.scheduled_tokens,
             "grid_tokens": self.grid_tokens,
+            "budget_full_steps": self.budget_full_steps,
             "scheduled_prefill_tokens": self.scheduled_prefill_tokens,
             "admitted_prompt_tokens": self.admitted_prompt_tokens,
             "blocks_in_use": self.pool.blocks_in_use,

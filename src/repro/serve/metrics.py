@@ -67,7 +67,7 @@ COUNTERS = frozenset({
     "truncated_requests", "finished_requests", "output_tokens",
     "d2h_fetches", "sibling_requests", "beam_forks", "masked_tokens",
     "draft_tokens", "accepted_tokens", "rejected_tokens", "bonus_tokens",
-    "draft_d2h_fetches",
+    "draft_d2h_fetches", "budget_full_steps",
 })
 GAUGES = frozenset({
     "blocks_in_use", "blocks_cached", "preempted_waiting",
