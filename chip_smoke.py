@@ -300,7 +300,8 @@ def serve_phase(checks: Checks, params, cfg, rng) -> None:
     vec = jnp.zeros((SLOTS,), jnp.int32)
     compiled = engine._step.lower(
         engine.params, {"tokens": tokens}, engine.caches, vec, vec,
-        engine._tables_dev, tokens).compile()
+        engine._tables_dev, tokens,
+        jnp.zeros((engine.step_rows,), jnp.int32)).compile()
     calls = tpu_kernel_calls(compiled.as_text())
     checks.check("serve: compiled step runs the kernels",
                  calls.get("tim_matmul_fused", 0) > 0

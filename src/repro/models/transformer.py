@@ -19,7 +19,7 @@ blocks recompute K/V from the (small) media embeddings each step.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +57,44 @@ def _norm_specs(cfg: ArchConfig):
 def _norm_apply(cfg: ArchConfig, p, x):
     return rmsnorm_apply(p, x) if cfg.norm == "rms" \
         else layernorm_apply(p, x)
+
+
+# ---------------------------------------------------------------------------
+# scheduled-token rows of a (slots, chunk) grid
+# ---------------------------------------------------------------------------
+
+class TokenRows(NamedTuple):
+    """The scheduled tokens of a padded ``(slots, chunk)`` grid, packed
+    into ``R`` rows for the token-wise work (embedding, norms, the
+    linear layers, RoPE, the residual).  ``idx`` (R,) holds each row's
+    flat grid index ``slot * chunk + col``; an index past the grid
+    (``>= slots * chunk``) marks a padding row.  Attention (and any
+    recurrent mixer) goes back to the grid through ``scatter`` and
+    returns through ``gather``, so it sees the padded step's layout."""
+    idx: jax.Array
+    slots: int
+    chunk: int
+
+    @property
+    def valid(self) -> jax.Array:
+        return self.idx < self.slots * self.chunk
+
+    @property
+    def slot(self) -> jax.Array:
+        return jnp.minimum(self.idx // self.chunk, self.slots - 1)
+
+    def gather(self, grid: jax.Array) -> jax.Array:
+        """(slots, chunk, ...) -> (R, 1, ...); padding rows read the
+        last cell and are never written back."""
+        flat = grid.reshape((self.slots * self.chunk,) + grid.shape[2:])
+        return jnp.take(flat, self.idx, axis=0, mode="clip")[:, None]
+
+    def scatter(self, rows: jax.Array) -> jax.Array:
+        """(R, 1, ...) -> (slots, chunk, ...), zero where no row lands."""
+        tail = rows.shape[2:]
+        flat = jnp.zeros((self.slots * self.chunk,) + tail, rows.dtype)
+        flat = flat.at[self.idx].set(rows[:, 0], mode="drop")
+        return flat.reshape((self.slots, self.chunk) + tail)
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +154,7 @@ _kv_dequantize = attn.kv_dequantize
 def _attn_block_apply(p, x, cfg: ArchConfig, positions, mode: str,
                       cache, cache_len, media, cross: bool,
                       n_new=None, block_tables=None, slot_map=None,
-                      seg_ids=None):
+                      seg_ids=None, rows=None):
     b, s, _ = x.shape
     hd, h, hk = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     pol = cfg.ternary
@@ -196,7 +234,12 @@ def _attn_block_apply(p, x, cfg: ArchConfig, positions, mode: str,
                 else:
                     rid = jnp.arange(b)[:, None]
                 pos = jnp.where(row < smax, rid * smax + row, cap)
-            widx = jnp.where(col < nn_[:, None], pos, cap).reshape(-1)
+            live = col < nn_[:, None]
+            if rows is not None:
+                # scheduled-token rows (R, 1): each row writes where
+                # its grid cell would, padding rows drop
+                pos, live = rows.gather(pos), rows.valid[:, None]
+            widx = jnp.where(live, pos, cap).reshape(-1)
 
             def scatter(pool, vals):
                 flat = pool.reshape((cap,) + pool.shape[2:])
@@ -239,11 +282,16 @@ def _attn_block_apply(p, x, cfg: ArchConfig, positions, mode: str,
                     chunk_kv=cfg.attn_chunk_kv,
                     block_tables=block_tables, **scale_kw)
             else:
-                o = attn.mixed_attention(q, kd, vd, cache_len + nn_,
+                # scheduled-token rows attend on the slot grid: the
+                # same kernel, grid and work as the padded step
+                qg = q if rows is None else rows.scatter(q)
+                o = attn.mixed_attention(qg, kd, vd, cache_len + nn_,
                                          cache_len,
                                          chunk_kv=cfg.attn_chunk_kv,
                                          block_tables=block_tables,
                                          **scale_kw)
+                if rows is not None:
+                    o = rows.gather(o)
 
     o = o.reshape(b, s, h * hd)
     o = ternary_dense_apply(p["o"], o, pol, cd)
@@ -294,15 +342,19 @@ def _block_specs(cfg: ArchConfig, spec: BlockSpec):
 
 def _block_apply(p, x, cfg: ArchConfig, spec: BlockSpec, positions,
                  mode, cache, cache_len, media, n_new=None,
-                 block_tables=None, slot_map=None, seg_ids=None):
+                 block_tables=None, slot_map=None, seg_ids=None,
+                 rows=None):
     aux = jnp.zeros((), jnp.float32)
     if spec.mixer in ("attn", "cross_attn"):
         x, new_cache = _attn_block_apply(
             p, x, cfg, positions, mode, cache, cache_len, media,
             spec.mixer == "cross_attn", n_new, block_tables, slot_map,
-            seg_ids)
+            seg_ids, rows)
     else:
         h_in = _norm_apply(cfg, p["ln1"], x)
+        if rows is not None:
+            # the recurrence runs along each slot's chunk: on the grid
+            h_in = rows.scatter(h_in)
         mcache = cache if (cache and "ssm" in cache) else None
         if seg_ids is not None and mcache is not None:
             # token-packed: per-slot recurrent state keyed by segment
@@ -313,6 +365,8 @@ def _block_apply(p, x, cfg: ArchConfig, spec: BlockSpec, positions,
             y, new_mcache = mamba_apply(p["mamba"], h_in, cfg.mamba,
                                         cfg.ternary, cfg.cdtype, mcache,
                                         n_new=n_new)
+        if rows is not None:
+            y = rows.gather(y)
         x = x + y.astype(x.dtype)
         new_cache = new_mcache if new_mcache is not None else cache
 
@@ -418,7 +472,8 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
             n_new: Optional[jax.Array] = None,
             block_tables: Optional[jax.Array] = None,
             slot_map: Optional[jax.Array] = None,
-            seg_ids: Optional[jax.Array] = None
+            seg_ids: Optional[jax.Array] = None,
+            rows: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """Returns (hidden (B,S,d), new_caches (or None), moe_aux_loss).
 
@@ -449,12 +504,38 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
     segment boundaries.  Per-token math is the padded grid's exactly
     (same masks, same chunk boundaries), so greedy decoding is
     token-for-token identical — docs/serving.md §token-packed.
+
+    Scheduled-token rows (paged 'mixed' + ``rows``): the batch keeps
+    the padded (slots, chunk) grid and ``rows`` ((R,) int32) lists the
+    flat grid index ``slot * chunk + col`` of each scheduled token (an
+    index past the grid, or a cell past its slot's ``n_new``, marks
+    padding).  The token-wise work runs on those R rows and only
+    attention's core goes back to the grid (``TokenRows``), so every
+    real token's values are the padded grid's; the hidden states
+    return on the grid, zero off the rows.
     """
     from repro.distrib.sharding import hint_constrain
 
+    grid = None
+    if rows is not None:
+        assert mode == "mixed" and slot_map is not None \
+            and seg_ids is None, "rows ride the paged mixed step"
+        slots, chunk = batch["tokens"].shape
+        # a row whose cell lies past its slot's n_new is padding
+        cell = jnp.minimum(rows, slots * chunk - 1)
+        real = (rows < slots * chunk) & (cell % chunk
+                                         < n_new[cell // chunk])
+        grid = TokenRows(jnp.where(real, rows, slots * chunk), slots,
+                         chunk)
+        batch = dict(batch, tokens=grid.gather(batch["tokens"]))
     x, media = embed_inputs(params, cfg, batch)
     b, s = x.shape[0], x.shape[1]
-    if mode in ("decode", "mixed"):
+    if grid is not None:
+        positions = grid.gather(cache_len[:, None]
+                                + jnp.arange(grid.chunk)[None, :])
+        if media is not None:
+            media = media[grid.slot]                # per-row media
+    elif mode in ("decode", "mixed"):
         positions = cache_len[:, None] + jnp.arange(s)[None, :]  # (B, S)
     else:
         positions = jnp.arange(s)[None, :]
@@ -473,7 +554,7 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
             x, nc, aux = _block_apply(
                 period_params[f"b{j}"], x, cfg, spec, positions, mode,
                 blk_cache, cache_len, media, n_new, block_tables,
-                slot_map, seg_ids)
+                slot_map, seg_ids, grid)
             x = hint_constrain(x, ("batch", "seq", None))
             new_caches[f"b{j}"] = nc if nc is not None else {}
             aux_total = aux_total + aux
@@ -505,6 +586,8 @@ def forward(params: Params, cfg: ArchConfig, batch: Dict[str, Any],
             (params["layers"], caches))
 
     x = _norm_apply(cfg, params["final_norm"], x)
+    if grid is not None:
+        x = grid.scatter(x)
     return x, new_caches, aux
 
 

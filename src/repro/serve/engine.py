@@ -15,8 +15,13 @@ jitted step function of fixed shape:
 
   unified_step : tokens (slots, chunk), per-slot cache_len write
                  offsets, per-slot n_new valid counts, per-slot block
-                 tables (slots, max_blocks), slot_map (slots, chunk)
+                 tables (slots, max_blocks), slot_map (slots, chunk),
+                 rows (R,): the grid cells of the scheduled tokens
               -> next-token logits (slots, vocab), updated caches
+
+The token-wise work (embedding, norms, the linear layers, RoPE, the
+residual) runs on the R scheduled-token rows, R fixed per engine
+(``step_rows``); attention alone runs on the (slots, chunk) grid.
 
 Every engine iteration fills that fixed token grid with a mix of work:
 each actively *decoding* slot contributes its 1 next token, and slots
@@ -79,6 +84,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation as _span
 
 from repro.configs.base import ArchConfig
+from repro.kernels.tim_matmul import M_ALIGN
 from repro.models import transformer as tfm
 from repro.nn.linear import TernaryPolicy
 from repro.serve.block_pool import (ROOT_HASH, BlockPool, chain_hash,
@@ -297,17 +303,28 @@ def make_unified_step(cfg: ArchConfig):
     return unified_step
 
 
+def step_rows(slots: int, chunk: int, token_budget: int) -> int:
+    """Rows of the engine step's token-wise work: the most tokens one
+    step can schedule (decodes are never stalled, so at most
+    ``max(token_budget, slots)``), rounded up to the TiM kernel's M
+    tile and never past the ``slots * chunk`` grid."""
+    most = max(token_budget, slots)
+    return min(-(-most // M_ALIGN) * M_ALIGN, slots * chunk)
+
+
 def make_paged_unified_step(cfg: ArchConfig):
     """THE engine step: the unified mixed prefill/decode step against a
     block-paged KV pool.  ``block_tables`` (slots, max_blocks) resolves
     logical reads; ``slot_map`` (slots, chunk) gives each new token's
-    physical write position (block * block_size + offset)."""
+    physical write position (block * block_size + offset).  ``rows``
+    (R,) runs the token-wise work on the scheduled tokens' grid cells
+    only (``tfm.TokenRows``); without it every grid cell runs."""
     def paged_step(params, batch, caches, cache_len, n_new,
-                   block_tables, slot_map):
+                   block_tables, slot_map, rows=None):
         hidden, caches, _ = tfm.forward(
             params, cfg, batch, mode="mixed", caches=caches,
             cache_len=cache_len, n_new=n_new,
-            block_tables=block_tables, slot_map=slot_map)
+            block_tables=block_tables, slot_map=slot_map, rows=rows)
         last = jnp.take_along_axis(
             hidden, jnp.maximum(n_new - 1, 0)[:, None, None], axis=1)
         lg = tfm.logits(params, cfg, last)
@@ -941,9 +958,10 @@ class ServeEngine:
         # _schedule() calls that left prompt tokens waiting because the
         # token budget ran out (a chunk-limited slice does not count)
         self.budget_full_steps = 0
-        # device-grid rows actually launched (padded: slots*chunk per
-        # step; packed: the power-of-two token bucket) — the
-        # denominator of metrics.summarize()'s padding_efficiency
+        # rows launched through the linear layers (plain: step_rows
+        # per step; speculative verify: slots*chunk; packed: the
+        # power-of-two token bucket) — the denominator of
+        # metrics.summarize()'s padding_efficiency
         self.grid_tokens = 0
         # finished-request partial-tail donations (satellite of the
         # token-packed PR): bid -> (chain tuple, tail-token tuple).
@@ -1016,6 +1034,8 @@ class ServeEngine:
                 np.float32)
 
         self.packed = bool(packed)
+        self.step_rows = step_rows(batch_slots, self.chunk,
+                                   self.token_budget)
         # one step fn per layout; the wrapper signature is shared (the
         # layout-specific operands ride in *sched, after the donated
         # caches at position 2)
@@ -1725,11 +1745,12 @@ class ServeEngine:
                          jnp.asarray(seg), self._tables_dev,
                          jnp.asarray(smap), jnp.asarray(last_idx))
             else:
-                bucket = self.slots * self.chunk
+                bucket = self.step_rows
                 batch = {"tokens": jnp.asarray(tokens)}
                 sched = (jnp.asarray(self.cache_len.copy()),
                          jnp.asarray(n_new),
-                         self._tables_dev, jnp.asarray(slot_map))
+                         self._tables_dev, jnp.asarray(slot_map),
+                         jnp.asarray(self._grid_rows(n_new)))
             if self.cfg.n_media_tokens:
                 batch["media"] = self._media_dev
         with _span("serve.launch"):
@@ -2250,6 +2271,19 @@ class ServeEngine:
             req.cum_logprob = score
         for c in slots_:
             self._finish_check(c)
+
+    def _grid_rows(self, n_new: np.ndarray) -> np.ndarray:
+        """The plain step's ``rows``: the flat grid index ``slot * chunk
+        + col`` of every scheduled token, slot-major, then the
+        out-of-grid sentinel ``slots * chunk`` up to ``step_rows``.  A
+        fresh array each step (the upload may read it late)."""
+        cols = np.arange(self.chunk)
+        cells = (np.arange(self.slots)[:, None] * self.chunk
+                 + cols)[cols < n_new[:, None]]
+        rows = np.full((self.step_rows,), self.slots * self.chunk,
+                       np.int32)
+        rows[:cells.size] = cells
+        return rows
 
     def _flatten_grid(self, tokens: np.ndarray, n_new: np.ndarray,
                       slot_map: np.ndarray):

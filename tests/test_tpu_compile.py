@@ -163,6 +163,33 @@ def _with_sharding(tree, sharding):
         lambda x: _sds(sharding, x.shape, x.dtype), tree)
 
 
+def _compile_engine_step(sharding, slots: int, rows=None):
+    """The engine's paged unified step at full width (2 layers) under
+    the TiM policy, compiled for the described chip; ``rows`` adds the
+    scheduled-token rows operand of that length."""
+    from repro.models import transformer as tfm
+    from repro.serve.block_pool import default_num_blocks
+    from repro.serve.engine import make_paged_unified_step, ternarize_model
+
+    cfg = _tim_cfg(2)
+    s = _sds_on(sharding)
+    params = _with_sharding(jax.eval_shape(
+        lambda k: ternarize_model(tfm.init(cfg, k), cfg),
+        jax.random.PRNGKey(0)), sharding)
+    num_blocks = default_num_blocks(slots, MAX_LEN, BLOCK)
+    caches = _with_sharding(jax.eval_shape(
+        lambda: tfm.init_paged_caches(cfg, slots, num_blocks, BLOCK)),
+        sharding)
+    vec = s((slots,), jnp.int32)
+    args = (params, {"tokens": s((slots, CHUNK), jnp.int32)}, caches, vec,
+            vec, s((slots, MAX_LEN // BLOCK), jnp.int32),
+            s((slots, CHUNK), jnp.int32))
+    if rows is not None:
+        args += (s((rows,), jnp.int32),)
+    return jax.jit(make_paged_unified_step(cfg),
+                   donate_argnums=(2,)).lower(*args).compile()
+
+
 @pytest.mark.parametrize("precision", ["default", "highest"])
 def test_engine_step_compiles_with_kernels(one_chip, monkeypatch,
                                            precision):
@@ -170,30 +197,28 @@ def test_engine_step_compiles_with_kernels(one_chip, monkeypatch,
     the TiM policy: every ternary matmul and the paged attention lower
     to Mosaic kernels, also under an ambient f32 matmul precision (the
     int8 MXU passes must not inherit it)."""
-    from repro.models import transformer as tfm
-    from repro.serve.block_pool import default_num_blocks
-    from repro.serve.engine import make_paged_unified_step, ternarize_model
-
-    cfg = _tim_cfg(2)
-    s = _sds_on(one_chip)
-    params = _with_sharding(jax.eval_shape(
-        lambda k: ternarize_model(tfm.init(cfg, k), cfg),
-        jax.random.PRNGKey(0)), one_chip)
-    num_blocks = default_num_blocks(SLOTS, MAX_LEN, BLOCK)
-    caches = _with_sharding(jax.eval_shape(
-        lambda: tfm.init_paged_caches(cfg, SLOTS, num_blocks, BLOCK)),
-        one_chip)
-    vec = s((SLOTS,), jnp.int32)
-    args = (params, {"tokens": s((SLOTS, CHUNK), jnp.int32)}, caches, vec,
-            vec, s((SLOTS, MAX_LEN // BLOCK), jnp.int32),
-            s((SLOTS, CHUNK), jnp.int32))
     # the dispatch asks the default backend, which here is the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with jax.default_matmul_precision(precision):
-        compiled = jax.jit(make_paged_unified_step(cfg),
-                           donate_argnums=(2,)).lower(*args).compile()
+        compiled = _compile_engine_step(one_chip, SLOTS)
     # one layer's calls (the layer loop is a scan): the q, k, v, o,
     # gate, up and down matmuls and the attention
+    assert _kernel_calls(compiled) == {"tim_matmul_fused": 7,
+                                       "paged_attention": 1}
+
+
+def test_engine_row_step_compiles_with_kernels(one_chip, monkeypatch):
+    """The engine's plain step as it serves the chatglm3-6b cells (24
+    slots, 16-token chunks, a budget of 40): the token-wise work on 64
+    scheduled-token rows runs the TiM kernels, and attention stays the
+    slot-grid kernel, not the per-token one."""
+    from repro.serve.engine import step_rows
+
+    slots = 24
+    rows = step_rows(slots, CHUNK, slots + CHUNK)
+    assert rows == 64
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile_engine_step(one_chip, slots, rows)
     assert _kernel_calls(compiled) == {"tim_matmul_fused": 7,
                                        "paged_attention": 1}
 
