@@ -454,7 +454,9 @@ def tim_matmul_fused_pallas(x_q: jax.Array, w_data: jax.Array,
         scratch_shapes=_acc_shapes(plan, (True, need_t, True, need_t)),
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="tim_matmul_fused",
+        # the storage format shows in a device trace: the packed variant
+        # carries the 2-bit unpack on the VPU
+        name="tim_matmul_fused_packed" if packed else "tim_matmul_fused",
     )(plan.x, plan.w, plan.w1, plan.w2, i12)
     return out[:m, :n]
 

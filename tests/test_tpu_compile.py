@@ -6,8 +6,10 @@ vector layouts, the integer ops the vector unit has.  The compiler is
 installed here and compiles for a chip described by
 ``get_topology_desc("v5e:2x2")``.  Every kernel variant on the serving
 path is compiled at chatglm3-6b widths (d_model 4096, d_ff 13696,
-32 heads / 2 KV heads of 128), and so is the engine's paged unified
-step with the model cut to 2 layers.
+32 heads / 2 KV heads of 128), paged attention also at yi-34b's 56
+heads / 8 KV heads, and the engine's paged unified step, with the
+model cut to 2 layers, at both models' widths as their benchmark
+cells serve them.
 
 The topology is described in a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU
@@ -99,7 +101,8 @@ def test_tim_kernel_compiles(one_chip, name, kw, bits, m, k, n):
         fn = lambda x, w, a, b, i1, i2: tk.tim_matmul_fused_pallas(  # noqa
             x, w, a, b, i1, i2, need_t=True, out_dtype=jnp.bfloat16, **kw)
         compiled = _compile(fn, x, w, sc, sc, one, one)
-        kernel = "tim_matmul_fused"
+        kernel = "tim_matmul_fused_packed" if packed else \
+            "tim_matmul_fused"
     else:
         fn = lambda x, w, a, b, st: tk.tim_matmul_bitserial_fused_pallas(  # noqa
             x, w, a, b, st, bits=bits, need_t=True,
@@ -111,16 +114,20 @@ def test_tim_kernel_compiles(one_chip, name, kw, bits, m, k, n):
 
 @pytest.mark.parametrize("sq", [1, CHUNK])
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
-def test_paged_attention_compiles(one_chip, sq, kv_dtype):
+@pytest.mark.parametrize("n_heads,n_kv", [(N_HEADS, N_KV), (56, 8)],
+                         ids=["32q2kv", "56q8kv"])
+def test_paged_attention_compiles(one_chip, sq, kv_dtype, n_heads, n_kv):
+    """Paged attention at chatglm3-6b's grouping (16 query heads per KV
+    head) and yi-34b's (7 per KV head: a 7 * sq row query block)."""
     s = _sds_on(one_chip)
     nblk = MAX_LEN // BLOCK
     nb = SLOTS * nblk + SLOTS
-    pool = s((nb, BLOCK, N_KV, HEAD_DIM), jnp.dtype(kv_dtype))
-    args = [s((SLOTS, sq, N_HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
+    pool = s((nb, BLOCK, n_kv, HEAD_DIM), jnp.dtype(kv_dtype))
+    args = [s((SLOTS, sq, n_heads, HEAD_DIM), jnp.bfloat16), pool, pool,
             s((SLOTS, nblk), jnp.int32), s((SLOTS,), jnp.int32),
             s((SLOTS,), jnp.int32)]
     if kv_dtype == "int8":
-        scales = s((nb, BLOCK, N_KV), jnp.bfloat16)
+        scales = s((nb, BLOCK, n_kv), jnp.bfloat16)
 
         def fn(q, k, v, t, vl, qo, ks, vs):
             return pa.paged_attention_pallas(
@@ -152,10 +159,19 @@ def test_paged_packed_attention_compiles(one_chip):
     assert _kernel_calls(compiled) == {"paged_packed_attention": 1}
 
 
-def _tim_cfg(n_layers: int):
-    cfg = get_config("chatglm3-6b").replace(n_layers=n_layers)
+def _tim_cfg(n_layers: int, arch: str = "chatglm3-6b", pack: bool = False):
+    cfg = get_config(arch).replace(n_layers=n_layers)
     return cfg.replace(ternary=cfg.ternary.replace(
-        enabled=True, encoding="asymmetric", act_mode="ternary"))
+        enabled=True, encoding="asymmetric", act_mode="ternary",
+        pack=pack))
+
+
+# (arch, slots, 2-bit packed codes, layers its benchmark cell serves):
+# each benchmark configuration as bench/configs/ serves it
+_SERVED = {
+    "chatglm3-6b": ("chatglm3-6b", 24, False, 28),
+    "yi-34b": ("yi-34b", 16, True, 15),
+}
 
 
 def _with_sharding(tree, sharding):
@@ -163,7 +179,8 @@ def _with_sharding(tree, sharding):
         lambda x: _sds(sharding, x.shape, x.dtype), tree)
 
 
-def _compile_engine_step(sharding, slots: int, rows=None):
+def _compile_engine_step(sharding, slots: int, rows=None,
+                         arch: str = "chatglm3-6b", pack: bool = False):
     """The engine's paged unified step at full width (2 layers) under
     the TiM policy, compiled for the described chip; ``rows`` adds the
     scheduled-token rows operand of that length."""
@@ -171,7 +188,7 @@ def _compile_engine_step(sharding, slots: int, rows=None):
     from repro.serve.block_pool import default_num_blocks
     from repro.serve.engine import make_paged_unified_step, ternarize_model
 
-    cfg = _tim_cfg(2)
+    cfg = _tim_cfg(2, arch, pack)
     s = _sds_on(sharding)
     params = _with_sharding(jax.eval_shape(
         lambda k: ternarize_model(tfm.init(cfg, k), cfg),
@@ -207,30 +224,39 @@ def test_engine_step_compiles_with_kernels(one_chip, monkeypatch,
                                        "paged_attention": 1}
 
 
-def test_engine_row_step_compiles_with_kernels(one_chip, monkeypatch):
-    """The engine's plain step as it serves the chatglm3-6b cells (24
-    slots, 16-token chunks, a budget of 40): the token-wise work on 64
-    scheduled-token rows runs the TiM kernels, and attention stays the
+@pytest.mark.parametrize("model,rows,kernel", [
+    ("chatglm3-6b", 64, "tim_matmul_fused"),
+    ("yi-34b", 32, "tim_matmul_fused_packed")])
+def test_engine_row_step_compiles_with_kernels(one_chip, monkeypatch,
+                                               model, rows, kernel):
+    """The engine's plain step as it serves each benchmark cell, with
+    16-token chunks and a budget of slots + chunk: chatglm3-6b on 24
+    slots and 64 scheduled-token rows with int8 codes, yi-34b on 16
+    slots and 32 rows (one M tile) with 2-bit packed codes and 8 KV
+    heads of 7 query heads.  The token-wise work runs the TiM kernels
+    (the packed variant under its own name), and attention stays the
     slot-grid kernel, not the per-token one."""
     from repro.serve.engine import step_rows
 
-    slots = 24
-    rows = step_rows(slots, CHUNK, slots + CHUNK)
-    assert rows == 64
+    arch, slots, pack, _ = _SERVED[model]
+    assert step_rows(slots, CHUNK, slots + CHUNK) == rows
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = _compile_engine_step(one_chip, slots, rows)
-    assert _kernel_calls(compiled) == {"tim_matmul_fused": 7,
-                                       "paged_attention": 1}
+    compiled = _compile_engine_step(one_chip, slots, rows, arch, pack)
+    assert _kernel_calls(compiled) == {kernel: 7, "paged_attention": 1}
 
 
-def test_period_conversion_fits_one_chip(one_chip):
+@pytest.mark.parametrize("model", sorted(_SERVED))
+def test_period_conversion_fits_one_chip(one_chip, model):
     """Serving params come from one period of fp32 masters at a time
     (serve/engine.init_serving): one period's conversion, at full width,
-    needs a small share of the chip beside the 28-period code stack."""
+    needs a small share of the chip beside the code stack its cell
+    serves (chatglm3-6b's 28 int8 periods, yi-34b's 15 packed ones) and
+    the fp32 embedding and head."""
     from repro.models import transformer as tfm
     from repro.serve.engine import ternarize_model
 
-    cfg = _tim_cfg(28)
+    arch, _, pack, n_layers = _SERVED[model]
+    cfg = _tim_cfg(n_layers, arch, pack)
     key = _sds(one_chip, (2,), jnp.uint32)
     compiled = _compile(
         lambda k: ternarize_model(tfm.init_period(cfg, k), cfg), key)
