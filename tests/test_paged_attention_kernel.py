@@ -1,4 +1,4 @@
-"""Pallas paged-attention kernel parity matrix (ISSUE-5 tentpole).
+"""Pallas paged-attention kernel parity matrix.
 
 The in-kernel block-table gather (`kernels/paged_attention.py`, run in
 interpret mode on CPU) is checked against the XLA-gather route of
@@ -23,9 +23,16 @@ asserted **bit-exact**:
     all-valid entries equals the plain kernel bit-for-bit;
   * the S=1 decode variant (causal term compiled out) equals the
     causal kernel bit-for-bit;
-  * ``normalize=False`` flash partials with a single chunk equal
-    ``distrib/decode_attn._local_partial`` (the lse-merge oracle).
+  * the live bound: against the block-grid kernel (``_paged_grid_ref``,
+    every table entry fetched and masked) the kernel agrees bit for
+    bit on every row, for every variant and context shape, and on a
+    pool whose dead blocks are NaN it copies only live blocks.
+
+``normalize=False`` flash partials with a single chunk match
+``distrib/decode_attn._local_partial`` (the lse-merge oracle) within
+the cross-program bound.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -275,3 +282,171 @@ def test_partials_match_local_partial_oracle(kv):
     np.testing.assert_allclose(np.asarray(m), np.asarray(m_o), **_ULP_TOL)
     np.testing.assert_allclose(np.asarray(l), np.asarray(l_o), **_ULP_TOL)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_o), **_ULP_TOL)
+
+
+# -- the live bound: bit-exact against the block-grid kernel ----------------
+#
+# The kernel DMAs only the blocks below kv_valid_len and skips dead
+# chunks; the block-grid oracle (tests/_paged_grid_ref.py) fetches and
+# masks every table entry.  Skipped work is the identity, so every row
+# -- padding rows included -- must agree to the bit.
+
+LB_ROWS, LB_BS, LB_CK = 4, 16, 64          # 4 entries a chunk
+LB_NBLK = 16                               # 4 chunks, 256 positions a row
+LB_CTX = LB_NBLK * LB_BS
+_CONTEXTS = {
+    "mid-block": [37, 5, 100, 250],
+    "block-edge": [32, 16, 80, 240],
+    "chunk-edge": [64, 128, 192, LB_CTX],
+    "mid-chunk": [40, 90, 150, 200],
+    "empty-and-one-block": [0, 16, 0, 7],
+    "all-full": [LB_CTX] * LB_ROWS,
+}
+# "bf16": the mixed variant over bf16 pools and queries, as served
+_VARIANTS = ["mixed", "decode", "packed", "int8", "compacted", "bf16"]
+
+
+def _lb_pools(seed, variant):
+    """A pool twice the rows' need, rows scattered over it at random."""
+    rng = np.random.default_rng(seed)
+    nb = 2 * LB_ROWS * LB_NBLK
+    k = rng.normal(size=(nb, LB_BS, HK, D)).astype(np.float32)
+    v = rng.normal(size=(nb, LB_BS, HK, D)).astype(np.float32)
+    tables = rng.permutation(nb)[:LB_ROWS * LB_NBLK].reshape(
+        LB_ROWS, LB_NBLK)
+    dtype = jnp.bfloat16 if variant == "bf16" else jnp.float32
+    pools = {"k_pool": jnp.asarray(k, dtype), "v_pool": jnp.asarray(v, dtype)}
+    if variant == "int8":
+        from repro.models.transformer import _kv_quantize
+        pools["k_pool"], pools["k_scale"] = _kv_quantize(pools["k_pool"])
+        pools["v_pool"], pools["v_scale"] = _kv_quantize(pools["v_pool"])
+    return pools, jnp.asarray(tables, jnp.int32)
+
+
+def _lb_call(variant, contexts, pools, tables, seed=0):
+    """One case: (live-bound kernel output, block-grid oracle as a
+    function of the pools, the physical block of every live (grid row,
+    table entry) -- what the kernel may DMA, once a KV head)."""
+    from _paged_grid_ref import grid_paged_attention
+    from repro.kernels.paged_attention import paged_packed_attention_pallas
+    rng = np.random.default_rng(seed + 100)
+    vlen = jnp.asarray(contexts, jnp.int32)
+    kw = dict(chunk_kv=LB_CK)
+
+    def prefixes(tbl, lens):
+        return [int(t) for row, n in zip(np.asarray(tbl), lens)
+                for t in row[:-(-int(n) // LB_BS)]]
+
+    if variant == "packed":
+        # the rows' last two tokens, then bucket padding (seg -1, vlen 0)
+        seg, tv = [], []
+        for r, n in enumerate(contexts):
+            for p in range(max(n - 2, 0), n):
+                seg.append(r)
+                tv.append(p + 1)
+        seg += [-1, -1]
+        tv += [0, 0]
+        seg_j = jnp.asarray(seg, jnp.int32)
+        tvlen = jnp.asarray(tv, jnp.int32)
+        qoff = jnp.maximum(tvlen - 1, 0)
+        q = jnp.asarray(rng.normal(size=(len(seg), 1, H, D)), jnp.float32)
+        got = paged_packed_attention_pallas(q, pools["k_pool"],
+                                            pools["v_pool"], tables, seg_j,
+                                            tvlen, q_offset=qoff, **kw)
+        tok_tables = tables[jnp.clip(seg_j, 0, LB_ROWS - 1)]
+        return got, lambda p: grid_paged_attention(
+            q, p["k_pool"], p["v_pool"], tok_tables, tvlen, q_offset=qoff,
+            **kw), prefixes(tok_tables, tv)
+    sq = 1 if variant == "decode" else 3
+    q = jnp.asarray(rng.normal(size=(LB_ROWS, sq, H, D)),
+                    pools["k_pool"].dtype if variant == "bf16"
+                    else jnp.float32)
+    if variant == "decode":
+        kw["causal"] = False
+    else:
+        kw["q_offset"] = jnp.maximum(vlen - sq, 0)
+    if variant == "compacted":
+        # a per-device compaction: each entry names its logical block
+        # (a shuffled order) and about half of them are local
+        keep = np.stack([rng.permutation(LB_NBLK) for _ in contexts])
+        sel = rng.integers(0, 2, size=keep.shape)
+        kw.update(logical_blocks=jnp.asarray(keep, jnp.int32),
+                  entry_valid=jnp.asarray(sel, jnp.int32), normalize=False)
+        tables = jnp.take_along_axis(tables, jnp.asarray(keep), axis=1)
+        live = [int(tables[r, e]) for r, n in enumerate(contexts)
+                for e in range(LB_NBLK)
+                if sel[r, e] and keep[r, e] * LB_BS < n]
+    else:
+        live = prefixes(tables, contexts)
+    scales = {k: pools[k] for k in ("k_scale", "v_scale") if k in pools}
+    got = paged_attention_pallas(q, pools["k_pool"], pools["v_pool"],
+                                 tables, vlen, **scales, **kw)
+    return got, lambda p: grid_paged_attention(
+        q, p["k_pool"], p["v_pool"], tables, vlen,
+        **{k: p[k] for k in scales}, **kw), live
+
+
+def _assert_bits(got, want):
+    for g, w in zip(*(x if isinstance(x, tuple) else (x,)
+                      for x in (got, want))):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("contexts", list(_CONTEXTS.values()),
+                         ids=list(_CONTEXTS))
+@pytest.mark.parametrize("variant", _VARIANTS)
+def test_live_bound_matches_block_grid_bit_exact(variant, contexts):
+    pools, tables = _lb_pools(31, variant)
+    got, oracle, _ = _lb_call(variant, contexts, pools, tables)
+    _assert_bits(got, oracle(pools))
+
+
+@pytest.fixture
+def dma_log(monkeypatch):
+    """Physical block of every K/V copy the kernel starts, as it runs."""
+    from jax.experimental.pallas import tpu as pltpu
+    real, log = pltpu.make_async_copy, []
+
+    class _Logged:
+        def __init__(self, src, dst, sem):
+            self.copy = real(src, dst, sem)
+            self.block = src.transforms[-1].indices[0]
+
+        def start(self):
+            jax.debug.callback(lambda b: log.append(int(b)), self.block)
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(pltpu, "make_async_copy", _Logged)
+    return log
+
+
+@pytest.mark.parametrize("variant", _VARIANTS)
+def test_poisoned_pool_is_never_read(variant, dma_log):
+    """Every pool block no live position reaches is NaN (its scales too
+    for int8 KV).  The kernel copies each live block exactly once per
+    KV head for K and for V and no other block, and stale scratch never
+    reaches p @ V: the output is finite and equals the block-grid
+    oracle's on the clean pool, bit for bit."""
+    contexts = [37, 0, 128, 7]
+    pools, tables = _lb_pools(41, variant)
+    clean, _, live = _lb_call(variant, contexts, pools, tables)
+    jax.block_until_ready(clean)
+    dma_log.clear()
+    dead = np.ones(pools["k_pool"].shape[0], bool)
+    dead[live] = False
+    assert dead.any() and live
+    poisoned = {}
+    for name, arr in pools.items():
+        a = np.array(arr)
+        if a.dtype != np.int8:                  # codes carry no NaN
+            a[dead] = np.nan
+        poisoned[name] = jnp.asarray(a, arr.dtype)
+    got, oracle, _ = _lb_call(variant, contexts, poisoned, tables)
+    jax.block_until_ready(got)
+    assert sorted(dma_log) == sorted(live * (2 * HK))
+    for g in got if isinstance(got, tuple) else (got,):
+        assert np.isfinite(np.asarray(g)).all()
+    _assert_bits(got, oracle(pools))
